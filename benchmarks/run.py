@@ -74,6 +74,8 @@ def main() -> None:
     import importlib
 
     from benchmarks import common
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     t0 = time.perf_counter()
     failures = []
     results = {}

@@ -60,9 +60,10 @@ from repro.obs.tracing import NULL_TRACER
 
 # Route row-slot block movement through the Pallas kernels
 # (kernels/gather_blocks.py / scatter_blocks.py, _hkv variants).  Default is
-# the jnp fast path: on this CPU-only container the kernels run in interpret
-# mode (Python-per-block — correct but slow); on TPU set
-# REPRO_PLANE_KERNEL=1 REPRO_KERNEL_INTERPRET=0 for the compiled DMA stream.
+# the jnp path.  The kernels compile through Mosaic on TPU and run
+# interpreted on CPU (correct but slow); whether the compiled DMA stream
+# beats the jnp gather on the chip is not measured yet, so the switch stays
+# opt-in until the benchmark decides it.  Read once, at import.
 USE_PALLAS_PLANE = os.environ.get("REPRO_PLANE_KERNEL", "0") == "1"
 
 
@@ -241,9 +242,14 @@ class _StagedDecodeFns(StageFns):
         self.plane_mesh = plane_mesh
         wrap = self.wrap
 
+        # with a plane_mesh, embed hands the first layer the same
+        # replicated sharding every attend stage hands the next layer, so
+        # layer 0's select/attend hit the jit cache of the others
+        pin = plane_mesh.replicate if plane_mesh is not None else (
+            lambda x: x)
         self.embed = wrap("embed",
                           lambda params, tokens:
-                          M.decode_embed(params, cfg, tokens))
+                          pin(M.decode_embed(params, cfg, tokens)))
         # select consumes and returns the layer's pool cache (arg 2): donate
         # so the append/meta update reuses the buffer instead of copying the
         # full pool per layer per iteration.  With a plane_mesh the pool-
@@ -404,9 +410,13 @@ class DevicePoolPlane:
         extra = (jax.tree.map(
             lambda x: jnp.zeros((b_cap,) + x.shape[1:], x.dtype),
             template["extra"]) if template["extra"] else {})
-        return {"caches": caches,
-                "cur_len": jnp.zeros((b_cap,), jnp.int32),
-                "extra": extra}
+        cur_len = jnp.zeros((b_cap,), jnp.int32)
+        if self.plane_mesh is not None:
+            # the logits stage hands cur_len back replicated on the mesh:
+            # start it there so the first step's stages hit the same jit
+            # cache entries as every later step's
+            cur_len = self.plane_mesh.replicate(cur_len)
+        return {"caches": caches, "cur_len": cur_len, "extra": extra}
 
     def _grow(self, b_cap: int, nb_cap: int) -> None:
         db = b_cap - self.b_cap

@@ -188,9 +188,10 @@ def _quantize_block_np(x: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     bit-for-bit (``np.rint`` == ``jnp.rint``, round-half-to-even)."""
     xf = x.astype(np.float32)
     amax = np.max(np.abs(xf), axis=(1, 2))
-    scales = (amax / 127.0).astype(np.float32)
-    # reciprocal-multiply in f32, same as the kernel/ref paths — division
-    # here would flip exact .5 rounding boundaries vs the kernels
+    # the kernels' rounding rule (quant_blocks.int8_scale): scale by the
+    # f32 constant 1/127, then reciprocal-multiply — a division would flip
+    # exact .5 rounding boundaries vs the kernels
+    scales = (amax * np.float32(1.0 / 127.0)).astype(np.float32)
     inv = np.where(scales > 0.0,
                    np.float32(1.0) / np.where(scales > 0.0, scales,
                                               np.float32(1.0)),

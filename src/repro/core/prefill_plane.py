@@ -227,6 +227,14 @@ class PrefillPlane:
 
     # -- capacity ----------------------------------------------------------
 
+    def _place(self, tree):
+        """New plane buffers start where the launches leave them:
+        replicated on the mesh when there is one, so the first launch and
+        every later one share a jit cache entry."""
+        if self.plane_mesh is None:
+            return tree
+        return self.plane_mesh.replicate(tree)
+
     def _pad_rows(self, v, db):
         return jnp.pad(v, ((0, db),) + ((0, 0),) * (v.ndim - 1))
 
@@ -239,9 +247,11 @@ class PrefillPlane:
         s_cap = max(self.s_cap, self.policy.bucket_tokens(need_tokens))
         if self.hidden is None:
             d = template_h.shape[-1]
-            self.hidden = jnp.zeros((b_cap, s_cap, d), template_h.dtype)
-            self._tok_len = jnp.zeros((b_cap,), jnp.int32)
-            self.rec = M._init_rec_states(self.cfg, b_cap, template_h.dtype)
+            self.hidden = self._place(
+                jnp.zeros((b_cap, s_cap, d), template_h.dtype))
+            self._tok_len = self._place(jnp.zeros((b_cap,), jnp.int32))
+            self.rec = self._place(
+                M._init_rec_states(self.cfg, b_cap, template_h.dtype))
             self._free = list(range(b_cap))
         elif b_cap != self.b_cap or s_cap != self.s_cap:
             db, ds = b_cap - self.b_cap, s_cap - self.s_cap
@@ -267,10 +277,11 @@ class PrefillPlane:
         if self.ctx_k is not None:
             return
         k_tail, v_tail = kv_tail_shapes
-        self.ctx_k = jnp.zeros((self.b_cap, self.s_cap) + k_tail, jnp.float32)
+        self.ctx_k = self._place(
+            jnp.zeros((self.b_cap, self.s_cap) + k_tail, jnp.float32))
         if v_tail is not None:
-            self.ctx_v = jnp.zeros((self.b_cap, self.s_cap) + v_tail,
-                                   jnp.float32)
+            self.ctx_v = self._place(
+                jnp.zeros((self.b_cap, self.s_cap) + v_tail, jnp.float32))
 
     # -- slot lifecycle ----------------------------------------------------
 

@@ -13,26 +13,30 @@ block axis as the 128-aligned minor-most dimension.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import resolve_interpret
+
 
 def _score_kernel(q_ref, mn_ref, mx_ref, out_ref):
-    # q: (1, 1, G, D); mn/mx: (1, 1, NBt, D); out: (1, 1, NBt)
+    # q: (1, 1, G, D); mn/mx: (1, 1, NBt, D); out: (1, 1, 1, NBt)
     q = q_ref[0, 0].astype(jnp.float32)                  # (G, D)
     mn = mn_ref[0, 0].astype(jnp.float32)                # (NBt, D)
     mx = mx_ref[0, 0].astype(jnp.float32)
     pos = jnp.maximum(q, 0.0)
     neg = jnp.minimum(q, 0.0)
     s = pos @ mx.T + neg @ mn.T                          # (G, NBt)
-    out_ref[0, 0] = jnp.max(s, axis=0)
+    out_ref[0, 0] = jnp.max(s, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("nb_tile", "interpret"))
 def block_score(q: jax.Array, meta_min: jax.Array, meta_max: jax.Array, *,
-                nb_tile: int = 128, interpret: bool = True) -> jax.Array:
+                nb_tile: int = 128, interpret: Optional[bool] = None
+                ) -> jax.Array:
     """q: (B, Hq, D); meta_min/max: (B, Hkv, NB, D) -> scores (B, Hkv, NB)."""
     B, Hq, D = q.shape
     _, Hkv, NB, _ = meta_min.shape
@@ -54,8 +58,11 @@ def block_score(q: jax.Array, meta_min: jax.Array, meta_max: jax.Array, *,
             pl.BlockSpec((1, 1, nb_tile, D), lambda b, h, n: (b, h, n, 0)),
             pl.BlockSpec((1, 1, nb_tile, D), lambda b, h, n: (b, h, n, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, nb_tile), lambda b, h, n: (b, h, n)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, NBp), jnp.float32),
-        interpret=interpret,
+        # a unit sublane axis keeps the output tile's last two dims
+        # (1, nb_tile): whole along the sublanes, 128-aligned along lanes
+        out_specs=pl.BlockSpec((1, 1, 1, nb_tile),
+                               lambda b, h, n: (b, h, 0, n)),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, 1, NBp), jnp.float32),
+        interpret=resolve_interpret(interpret),
     )(qg, meta_min, meta_max)
-    return out[:, :, :NB]
+    return out[:, :, 0, :NB]

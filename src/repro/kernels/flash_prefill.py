@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -71,7 +73,7 @@ def _make_kernel(scale: float, q_tile: int, k_tile: int, nK: int,
 def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   scale: Optional[float] = None, q_offset: int = 0,
                   q_tile: int = 128, k_tile: int = 128,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, Sq, Hq, D); k/v: (B, Sk, Hkv, D).  Causal.  -> (B, Sq, Hq, Dv).
 
     GQA groups are folded into the query tile: the kernel sees
@@ -114,7 +116,7 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((G * q_tile, 1), jnp.float32),
             pltpu.VMEM((G * q_tile, Dv), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt.reshape(B, Hkv, nQ, G * q_tile, D), kt, vt)
     # back to (B, Sq, Hq, Dv)
     out = (out.reshape(B, Hkv, nQ, G, q_tile, Dv)

@@ -9,17 +9,20 @@ full link utilisation, no per-copy descriptor overhead.
 
 On a real deployment the source pool lives in host memory
 (``jax.device_put(pool, ...memory_kind="pinned_host")``) and the same index
-map expresses the H2D stream; here the kernel is validated in interpret
-mode against ``ref.gather_blocks``.
+map expresses the H2D stream.  Checked in interpret mode against
+``ref.gather_blocks`` and compiled through Mosaic for a described v5e.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 
 def _gather_kernel(idx_ref, pool_ref, out_ref):
@@ -29,7 +32,7 @@ def _gather_kernel(idx_ref, pool_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_blocks(pool: jax.Array, idx: jax.Array, *,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: Optional[bool] = None) -> jax.Array:
     """pool: (NB, bs, D); idx: (K,) int32 -> (K, bs, D)."""
     NB, bs, D = pool.shape
     K = idx.shape[0]
@@ -45,7 +48,7 @@ def gather_blocks(pool: jax.Array, idx: jax.Array, *,
         _gather_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((K, bs, D), pool.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(idx.astype(jnp.int32), pool)
 
 
@@ -55,7 +58,7 @@ def _gather_hkv_kernel(idx_ref, pool_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_blocks_hkv(pool: jax.Array, idx: jax.Array, *,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: Optional[bool] = None) -> jax.Array:
     """Head-major fused gather: pool (H, NB, bs, D); idx (K,) int32 ->
     (H, K, bs, D).
 
@@ -80,5 +83,5 @@ def gather_blocks_hkv(pool: jax.Array, idx: jax.Array, *,
         _gather_hkv_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, K, bs, D), pool.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(idx.astype(jnp.int32), pool)

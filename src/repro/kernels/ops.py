@@ -1,72 +1,15 @@
-"""jit'd public wrappers for the Pallas kernels.
+"""Public entry points of the Pallas kernels.
 
-``INTERPRET`` defaults to True (this container is CPU-only; interpret mode
-executes the kernel bodies in Python for correctness validation).  On a real
-TPU deployment set ``REPRO_KERNEL_INTERPRET=0`` to compile via Mosaic.
+Each kernel decides at trace time whether it is interpreted (CPU) or
+compiled through Mosaic (TPU); see ``repro.kernels.platform``.
 """
-from __future__ import annotations
-
-import os
-
-
-from repro.kernels.block_score import block_score as _block_score
-from repro.kernels.flash_prefill import flash_prefill as _flash_prefill
-from repro.kernels.gather_blocks import gather_blocks as _gather_blocks
-from repro.kernels.gather_blocks import gather_blocks_hkv as _gather_blocks_hkv
-from repro.kernels.quant_blocks import dequantize_blocks as _dequantize_blocks
-from repro.kernels.quant_blocks import (
-    dequantize_scatter_blocks as _dequantize_scatter_blocks)
-from repro.kernels.quant_blocks import quantize_blocks as _quantize_blocks
-from repro.kernels.scatter_blocks import scatter_blocks as _scatter_blocks
-from repro.kernels.scatter_blocks import (
-    scatter_blocks_hkv as _scatter_blocks_hkv)
-from repro.kernels.sparse_decode_attention import (
-    sparse_decode_attention as _sparse_decode_attention)
-
-INTERPRET = os.environ.get("REPRO_KERNEL_INTERPRET", "1") != "0"
-
-
-def gather_blocks(pool, idx):
-    return _gather_blocks(pool, idx, interpret=INTERPRET)
-
-
-def scatter_blocks(pool, new_kv, dest_blocks):
-    return _scatter_blocks(pool, new_kv, dest_blocks, interpret=INTERPRET)
-
-
-def gather_blocks_hkv(pool, idx):
-    return _gather_blocks_hkv(pool, idx, interpret=INTERPRET)
-
-
-def scatter_blocks_hkv(pool, new_kv, dest_blocks):
-    return _scatter_blocks_hkv(pool, new_kv, dest_blocks, interpret=INTERPRET)
-
-
-def quantize_blocks(blocks):
-    return _quantize_blocks(blocks, interpret=INTERPRET)
-
-
-def dequantize_blocks(q, scales):
-    return _dequantize_blocks(q, scales, interpret=INTERPRET)
-
-
-def dequantize_scatter_blocks(pool, q, scales, dest_blocks):
-    return _dequantize_scatter_blocks(pool, q, scales, dest_blocks,
-                                      interpret=INTERPRET)
-
-
-def block_score(q, meta_min, meta_max, nb_tile: int = 128):
-    return _block_score(q, meta_min, meta_max, nb_tile=nb_tile,
-                        interpret=INTERPRET)
-
-
-def sparse_decode_attention(q, k_pool, v_pool, block_idx, sel_valid, cur_len,
-                            scale=None):
-    return _sparse_decode_attention(q, k_pool, v_pool, block_idx, sel_valid,
-                                    cur_len, scale=scale, interpret=INTERPRET)
-
-
-def flash_prefill(q, k, v, scale=None, q_offset: int = 0,
-                  q_tile: int = 128, k_tile: int = 128):
-    return _flash_prefill(q, k, v, scale=scale, q_offset=q_offset,
-                          q_tile=q_tile, k_tile=k_tile, interpret=INTERPRET)
+from repro.kernels.block_score import block_score  # noqa: F401
+from repro.kernels.flash_prefill import flash_prefill  # noqa: F401
+from repro.kernels.gather_blocks import (  # noqa: F401
+    gather_blocks, gather_blocks_hkv)
+from repro.kernels.quant_blocks import (  # noqa: F401
+    dequantize_blocks, dequantize_scatter_blocks, quantize_blocks)
+from repro.kernels.scatter_blocks import (  # noqa: F401
+    scatter_blocks, scatter_blocks_hkv)
+from repro.kernels.sparse_decode_attention import (  # noqa: F401
+    sparse_decode_attention)

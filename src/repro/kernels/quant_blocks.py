@@ -19,43 +19,79 @@ Three kernels, mirroring the fp transfer pair:
   fused in: lands int8 restore payloads straight into a request's fp
   device slots in one launch (restore-before-use stays a single fused op).
 
-All are validated in interpret mode against the ``ref.py`` oracles.
-Rounding is ``jnp.rint`` (round-half-to-even) so the numpy host-pool path
-(``np.rint``) is bit-identical to the kernel path.
+All are checked in interpret mode against the ``ref.py`` oracles, and
+compile through Mosaic at the served widths (tests/test_tpu_compile.py).
+Kernel, oracle and the numpy host-pool twin share one rounding:
+``int8_scale`` below (scale = amax * f32(1/127), then its reciprocal) and
+``rint`` (round-half-to-even).  A division by the constant 127 would be
+rewritten to a reciprocal multiply inside a compiled kernel but not in an
+eager oracle, and the one-ulp difference in the scale flips exact .5
+boundaries, which bf16 inputs hit often.
+
+Scales are (H, K) f32.  Mosaic tiles the last two dims of a block by
+(8, 128) unless they span the array, so a per-(head, block) ``(1, 1)``
+scale block cannot compile: ``quantize_blocks`` keeps the whole scale
+array in ONE output block, resident across the grid, and the dequantizers
+read it whole from SMEM.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import resolve_interpret
 
-def _quant_kernel(blk_ref, q_ref, scale_ref):
-    x = blk_ref[...].astype(jnp.float32)
-    amax = jnp.max(jnp.abs(x))
-    scale = amax / 127.0
-    # explicit reciprocal-multiply (not x/scale): XLA rewrites division to
-    # reciprocal-multiply in some contexts and not others, which flips
-    # exact .5 rounding boundaries — this keeps the kernel, the jnp ref
-    # oracle and the numpy host-pool path bit-identical
+
+INV_127 = np.float32(1.0 / 127.0)
+
+
+def int8_scale(amax):
+    """amax (any shape, f32) -> (scale, inv): the one rounding rule of the
+    int8 tier.  All-zero blocks get scale 0 and inv 1 (they quantize to
+    0 and dequantize to exact zeros)."""
+    scale = amax * INV_127
     inv = jnp.where(scale > 0.0, 1.0 / jnp.where(scale > 0.0, scale, 1.0),
                     1.0)
-    scale_ref[...] = jnp.full(scale_ref.shape, scale, jnp.float32)
-    q_ref[...] = jnp.clip(jnp.rint(x * inv), -127.0, 127.0).astype(jnp.int8)
+    return scale, inv
+
+
+def _quant_kernel(blk_ref, q_ref, scale_ref):
+    h, i = pl.program_id(0), pl.program_id(1)
+    x = blk_ref[0, 0].astype(jnp.float32)                # (bs, D)
+    amax = jnp.max(jnp.max(jnp.abs(x), axis=1, keepdims=True), axis=0,
+                   keepdims=True)                        # (1, 1)
+    scale, inv = int8_scale(amax)
+    q_ref[0, 0] = jnp.clip(jnp.rint(x * inv), -127.0, 127.0
+                           ).astype(jnp.int8)
+
+    @pl.when((h == 0) & (i == 0))
+    def _init():
+        scale_ref[...] = jnp.zeros_like(scale_ref)
+
+    # the (H, K) scale block stays in VMEM for the whole grid: set this
+    # step's entry with a masked full-tile select
+    shape = scale_ref.shape
+    hit = ((jax.lax.broadcasted_iota(jnp.int32, shape, 0) == h)
+           & (jax.lax.broadcasted_iota(jnp.int32, shape, 1) == i))
+    scale_ref[...] = jnp.where(hit, scale, scale_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def quantize_blocks(blocks: jax.Array, *, interpret: bool = True
+def quantize_blocks(blocks: jax.Array, *,
+                    interpret: Optional[bool] = None
                     ) -> tuple[jax.Array, jax.Array]:
     """blocks: (H, K, bs, D) fp -> (q (H, K, bs, D) int8, scales (H, K) f32).
 
     One grid step per (kv-head, block); the amax reduction and the
-    divide/round run on the VPU over the (bs, D) tile.  int8 tiles want
-    (32, 128) alignment on real TPUs — block_size >= 32 and head_dim a
-    multiple of 128 satisfy it; interpret mode accepts any shape."""
+    scale/round run on the VPU over the (bs, D) tile.  The (bs, D) data
+    tile compiles when bs is a multiple of 32 (the int8 sublane tile) and
+    D spans the head axis or is a multiple of 128."""
     H, K, bs, D = blocks.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
@@ -63,7 +99,7 @@ def quantize_blocks(blocks: jax.Array, *, interpret: bool = True
         in_specs=[pl.BlockSpec((1, 1, bs, D), lambda h, i: (h, i, 0, 0))],
         out_specs=[
             pl.BlockSpec((1, 1, bs, D), lambda h, i: (h, i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda h, i: (h, i)),
+            pl.BlockSpec((H, K), lambda h, i: (0, 0)),
         ],
     )
     return pl.pallas_call(
@@ -73,18 +109,18 @@ def quantize_blocks(blocks: jax.Array, *, interpret: bool = True
             jax.ShapeDtypeStruct((H, K, bs, D), jnp.int8),
             jax.ShapeDtypeStruct((H, K), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(blocks)
 
 
 def _dequant_kernel(q_ref, scale_ref, out_ref):
-    scale = scale_ref[0, 0]
+    scale = scale_ref[pl.program_id(0), pl.program_id(1)]
     out_ref[...] = q_ref[...].astype(jnp.float32) * scale
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def dequantize_blocks(q: jax.Array, scales: jax.Array, *,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: Optional[bool] = None) -> jax.Array:
     """q: (H, K, bs, D) int8, scales: (H, K) f32 -> (H, K, bs, D) f32."""
     H, K, bs, D = q.shape
     assert scales.shape == (H, K)
@@ -93,7 +129,7 @@ def dequantize_blocks(q: jax.Array, scales: jax.Array, *,
         grid=(H, K),
         in_specs=[
             pl.BlockSpec((1, 1, bs, D), lambda h, i: (h, i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda h, i: (h, i)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),           # scales
         ],
         out_specs=pl.BlockSpec((1, 1, bs, D), lambda h, i: (h, i, 0, 0)),
     )
@@ -101,14 +137,14 @@ def dequantize_blocks(q: jax.Array, scales: jax.Array, *,
         _dequant_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, K, bs, D), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, scales.astype(jnp.float32))
 
 
 def _dequant_scatter_kernel(dest_ref, q_ref, scale_ref, pool_in_ref,
                             pool_out_ref):
     del pool_in_ref  # aliased with pool_out_ref; unvisited blocks persist
-    scale = scale_ref[0, 0]
+    scale = scale_ref[pl.program_id(0), pl.program_id(1)]
     pool_out_ref[...] = (q_ref[...].astype(jnp.float32) * scale
                          ).astype(pool_out_ref.dtype)
 
@@ -116,7 +152,7 @@ def _dequant_scatter_kernel(dest_ref, q_ref, scale_ref, pool_in_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def dequantize_scatter_blocks(pool: jax.Array, q: jax.Array,
                               scales: jax.Array, dest_blocks: jax.Array, *,
-                              interpret: bool = True) -> jax.Array:
+                              interpret: Optional[bool] = None) -> jax.Array:
     """Fused dequant + head-major block scatter (quantized FlashH2D restore).
 
     pool: (H, NB, bs, D) fp device slots; q: (H, K, bs, D) int8 payload;
@@ -134,8 +170,7 @@ def dequantize_scatter_blocks(pool: jax.Array, q: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, bs, D),
                          lambda h, i, dref: (h, i, 0, 0)),        # q
-            pl.BlockSpec((1, 1),
-                         lambda h, i, dref: (h, i)),              # scales
+            pl.BlockSpec(memory_space=pltpu.SMEM),               # scales
             pl.BlockSpec((1, 1, bs, D),
                          lambda h, i, dref: (h, dref[i], 0, 0)),  # pool in
         ],
@@ -147,5 +182,5 @@ def dequantize_scatter_blocks(pool: jax.Array, q: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={3: 0},  # pool (arg idx incl. prefetch) -> out 0
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(dest_blocks.astype(jnp.int32), q, scales.astype(jnp.float32), pool)

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.dsa import score_blocks, sparse_decode_attention_ref
+from repro.kernels.quant_blocks import int8_scale
 
 NEG_INF = -1e30
 
@@ -48,16 +49,12 @@ def quantize_blocks(blocks: jax.Array) -> tuple[jax.Array, jax.Array]:
     (q (H, K, bs, D) int8, scales (H, K) f32).
 
     Symmetric per-(head, block) quantization: scale = amax/127 over each
-    (bs, D) tile, q = clip(rint(x/scale), -127, 127).  All-zero blocks get
+    (bs, D) tile, q = clip(rint(x * (1/scale)), -127, 127).  All-zero blocks get
     scale 0 (and quantize to 0) — dequant maps them back to exact zeros."""
     x = blocks.astype(jnp.float32)
     amax = jnp.max(jnp.abs(x), axis=(-2, -1))
-    scales = amax / 127.0
-    # reciprocal-multiply, NOT x/scale — keeps exact .5 rounding
-    # boundaries identical across the kernel / ref / numpy paths (XLA
-    # rewrites division inconsistently between compilation contexts)
-    inv = jnp.where(scales > 0.0,
-                    1.0 / jnp.where(scales > 0.0, scales, 1.0), 1.0)
+    # the kernel's own rounding rule (see quant_blocks.int8_scale)
+    scales, inv = int8_scale(amax)
     q = jnp.clip(jnp.rint(x * inv[..., None, None]), -127.0, 127.0)
     return q.astype(jnp.int8), scales
 

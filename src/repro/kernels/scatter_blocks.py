@@ -13,11 +13,14 @@ Validated in interpret mode against ``ref.scatter_blocks``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 
 def _scatter_kernel(dest_ref, new_ref, pool_in_ref, pool_out_ref):
@@ -27,7 +30,7 @@ def _scatter_kernel(dest_ref, new_ref, pool_in_ref, pool_out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def scatter_blocks(pool: jax.Array, new_kv: jax.Array, dest_blocks: jax.Array,
-                   *, interpret: bool = True) -> jax.Array:
+                   *, interpret: Optional[bool] = None) -> jax.Array:
     """pool: (NB, bs, D); new_kv: (T, D) with T = n_new*bs (contiguous);
     dest_blocks: (n_new,) int32.  Returns updated pool."""
     NB, bs, D = pool.shape
@@ -48,7 +51,7 @@ def scatter_blocks(pool: jax.Array, new_kv: jax.Array, dest_blocks: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={2: 0},  # pool (arg idx incl. prefetch) -> out 0
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(dest_blocks.astype(jnp.int32), new_blk, pool)
 
 
@@ -60,7 +63,7 @@ def _scatter_hkv_kernel(dest_ref, new_ref, pool_in_ref, pool_out_ref):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def scatter_blocks_hkv(pool: jax.Array, new_kv: jax.Array,
                        dest_blocks: jax.Array, *,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: Optional[bool] = None) -> jax.Array:
     """Head-major block scatter: pool (H, NB, bs, D); new_kv (H, K, bs, D);
     dest_blocks (K,) int32.  Returns the updated pool (aliased in place).
 
@@ -89,5 +92,5 @@ def scatter_blocks_hkv(pool: jax.Array, new_kv: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={2: 0},  # pool (arg idx incl. prefetch) -> out 0
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(dest_blocks.astype(jnp.int32), new_kv, pool)

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -77,7 +79,7 @@ def sparse_decode_attention(q: jax.Array, k_pool: jax.Array,
                             v_pool: jax.Array, block_idx: jax.Array,
                             sel_valid: jax.Array, cur_len: jax.Array, *,
                             scale: Optional[float] = None,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, Hq, D); pools: (B, Hkv, NB, bs, D[v]); block_idx/sel_valid:
     (B, Hkv, K); cur_len: (B,) int32.  Returns (B, Hq, Dv)."""
     B, Hq, D = q.shape
@@ -113,7 +115,7 @@ def sparse_decode_attention(q: jax.Array, k_pool: jax.Array,
         _make_kernel(scale, bs, K),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_idx.astype(jnp.int32), sel_valid.astype(jnp.int32),
       cur_len.astype(jnp.int32), qg, k_pool, v_pool)
     return out.reshape(B, Hq, Dv)

@@ -9,20 +9,28 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """Single-pod (16, 16) = 256 chips; multi-pod (2, 16, 16) = 512."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(model_axis: int = 1):
-    """Whatever this process actually has (tests / examples on CPU)."""
+    """Whatever devices this process has (CPU tests, one chip or four).
+
+    Auto axes: the planes place their arrays with ``NamedSharding`` and
+    ``with_sharding_constraint`` and let GSPMD propagate the rest; under
+    jax's default explicit axes the sharded prefill update would be a
+    sharding-type error."""
     n = len(jax.devices())
     data = n // model_axis
-    return jax.make_mesh((data, model_axis), ("data", "model"))
+    return jax.make_mesh((data, model_axis), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def dp_axes(mesh) -> Tuple[str, ...]:

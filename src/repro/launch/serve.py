@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ALL_ARCHS, get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serving.engine import EngineConfig, ServingEngine
 from repro.serving.request import Request
@@ -50,6 +51,7 @@ def main(argv=None) -> int:
                     help="print the Prometheus text exposition of the "
                          "final metrics snapshot")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     obs = args.obs or bool(args.trace_out) or None   # None -> REPRO_OBS env
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
@@ -79,8 +81,11 @@ def main(argv=None) -> int:
           f"obs={int(s['obs.enabled'])}")
     print(f"finished={m.num_finished}/{args.requests} "
           f"iters={s['engine.iterations']:.0f}")
+    clock = ("measured" if eng.eng.charge_real_time
+             else "modeled (cost model)")
     print(f"mean TTFT {m.mean_ttft*1e3:.2f} ms | mean TBT "
-          f"{m.mean_tbt*1e3:.3f} ms | {m.token_throughput:.1f} tok/s")
+          f"{m.mean_tbt*1e3:.3f} ms | {m.token_throughput:.1f} tok/s "
+          f"[{clock}]")
     print(f"FlashH2D: {s['kv.h2d_calls']:.0f} fused launches, "
           f"{s['kv.h2d_blocks']:.0f} blocks, {s['kv.h2d_bytes']/1e6:.2f} MB")
     print(f"FlashD2H: {s['kv.d2h_calls']:.0f} saves, "

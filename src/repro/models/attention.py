@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import dsa
-from repro.models.common import (DSAConfig, ModelConfig, apply_rope, dense_init, rms_norm, shard_map_compat, split_keys)
+from repro.models.common import (DSAConfig, ModelConfig, apply_rope, dense_init, rms_norm, split_keys)
 
 NEG_INF = -1e30
 
@@ -451,10 +451,10 @@ def cp_mla_decode_attention(cfg: ModelConfig, q_eff, latent, cache, cur_len,
     lat_s = P(dp, None)
     pool_s = P(dp, None, model_axis, None, None)
     meta_s = P(*([dp, None, model_axis] + [None] * (cache["meta"].ndim - 3)))
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         lambda q_, lt_, kp_, mt_, cl_: _cp_mla_decode_local(
             cfg, q_, lt_, kp_, mt_, cl_, model_axis),
-        mesh=pm.mesh,
+        check_vma=False, mesh=pm.mesh,
         in_specs=(vec, lat_s, pool_s, meta_s, P(dp)),
         out_specs=(vec, pool_s, meta_s, vec))
     o_lat, kpool, meta, idx = fn(q_eff, latent, cache["k"], cache["meta"],
@@ -477,10 +477,10 @@ def cp_decode_attention(cfg: ModelConfig, q, k, v, cache, cur_len, *, pm):
     pool_s = P(dp, None, model_axis, None, None)
     meta_s = P(*([dp, None, model_axis]
                  + [None] * (cache["meta"].ndim - 3)))
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         lambda q_, k_, v_, kp_, vp_, mt_, cl_: _cp_decode_local(
             cfg, q_, k_, v_, kp_, vp_, mt_, cl_, model_axis),
-        mesh=pm.mesh,
+        check_vma=False, mesh=pm.mesh,
         in_specs=(vec, vec, vec, pool_s, pool_s, meta_s, P(dp)),
         out_specs=(vec, pool_s, pool_s, meta_s, vec))
     o, kpool, vpool, meta, idx = fn(q, k, v, cache["k"], cache["v"],
@@ -553,8 +553,8 @@ def gqa_select_step_cp(p: Dict[str, jax.Array], cfg: ModelConfig,
             idx_, valid_ = dsa.select_blocks(scores, cfg.dsa, cl_ + 1)
             return kp_, vp_, mt_, idx_, valid_
 
-        fn = shard_map_compat(
-            body, mesh=pm.mesh,
+        fn = jax.shard_map(
+            body, check_vma=False, mesh=pm.mesh,
             in_specs=(P(dp, m, None, None), hvec, hvec, pool_s, pool_s,
                       meta_s, vec, vec),
             out_specs=(pool_s, pool_s, meta_s, P(dp, m, None),
@@ -586,8 +586,8 @@ def gqa_select_step_cp(p: Dict[str, jax.Array], cfg: ModelConfig,
         idx_, valid_ = dsa.select_blocks(scores, cfg.dsa, cl_ + 1)
         return kp_, vp_, mt_, idx_, valid_
 
-    fn = shard_map_compat(
-        body, mesh=pm.mesh,
+    fn = jax.shard_map(
+        body, check_vma=False, mesh=pm.mesh,
         in_specs=(P(dp, None, None), P(dp, None, None), P(dp, None, None),
                   pool_s, pool_s, meta_s, vec, vec),
         out_specs=(pool_s, pool_s, meta_s, P(dp, None, None),
@@ -623,8 +623,8 @@ def gqa_attend_step_cp(p: Dict[str, jax.Array], cfg: ModelConfig,
                                                 nl_)
             return o.reshape(Bl, Hl, G, o.shape[-1])
 
-        fn = shard_map_compat(
-            body, mesh=pm.mesh,
+        fn = jax.shard_map(
+            body, check_vma=False, mesh=pm.mesh,
             in_specs=(P(dp, m, None, None), pool_s, pool_s, P(dp),
                       P(dp, m, None), P(dp, m, None)),
             out_specs=P(dp, m, None, None))
@@ -647,8 +647,8 @@ def gqa_attend_step_cp(p: Dict[str, jax.Array], cfg: ModelConfig,
         acc_g = jax.lax.psum(acc * corr[..., None], m)
         return (acc_g / jnp.maximum(l_g, 1e-30)[..., None]).astype(q_.dtype)
 
-    fn = shard_map_compat(
-        body, mesh=pm.mesh,
+    fn = jax.shard_map(
+        body, check_vma=False, mesh=pm.mesh,
         in_specs=(P(dp, None, None), pool_s, pool_s, P(dp),
                   P(dp, None, None), P(dp, None, None)),
         out_specs=P(dp, None, None))
@@ -690,8 +690,8 @@ def mla_select_step_cp(p: Dict[str, jax.Array], cfg: ModelConfig,
         idx_, valid_ = dsa.select_blocks(scores, cfg.dsa, cl_ + 1)
         return kp_, mt_, idx_, valid_
 
-    fn = shard_map_compat(
-        body, mesh=pm.mesh,
+    fn = jax.shard_map(
+        body, check_vma=False, mesh=pm.mesh,
         in_specs=(P(dp, None, None), P(dp, None), pool_s, meta_s,
                   P(dp), P(dp)),
         out_specs=(pool_s, meta_s, P(dp, None, None), P(dp, None, None)))
@@ -731,8 +731,8 @@ def mla_attend_step_cp(p: Dict[str, jax.Array], cfg: ModelConfig,
         acc_g = jax.lax.psum(acc * corr[..., None], m)
         return (acc_g / jnp.maximum(l_g, 1e-30)[..., None]).astype(q_.dtype)
 
-    fn = shard_map_compat(
-        body, mesh=pm.mesh,
+    fn = jax.shard_map(
+        body, check_vma=False, mesh=pm.mesh,
         in_specs=(P(dp, None, None), pool_s, P(dp), P(dp, None, None),
                   P(dp, None, None)),
         out_specs=P(dp, None, None))

@@ -271,16 +271,3 @@ def take_layer(stacked: Any, idx) -> Any:
 
 def num_params(tree: Any) -> int:
     return sum(int(x.size) for x in jax.tree.leaves(tree))
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map with unchecked replication, across jax versions.
-
-    jax >= 0.6 exposes jax.shard_map(check_vma=...); older releases only
-    have jax.experimental.shard_map.shard_map(check_rep=...)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)

@@ -227,7 +227,6 @@ def moe_apply_ep(p: Dict, cfg: ModelConfig, x: jax.Array, *,
     """shard_map expert-parallel MoE: batch over `dp_axes`, experts over
     `model_axis`; ONE psum over `model` as the combine collective."""
     from jax.sharding import PartitionSpec as P
-    from repro.models.common import shard_map_compat
 
     # drop batch sharding when B doesn't divide the dp axes (e.g. batch=1
     # long-context decode — experts still parallel over `model`)
@@ -247,10 +246,10 @@ def moe_apply_ep(p: Dict, cfg: ModelConfig, x: jax.Array, *,
         w_spec["dense"] = {"w_gate": P(None, None), "w_up": P(None, None),
                            "w_down": P(None, None)}
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         lambda pp, xx: _moe_local(pp, cfg, xx, model_axis, dp_axes, EP_IMPL,
                                   drop_free),
-        mesh=mesh,
+        check_vma=False, mesh=mesh,
         in_specs=(w_spec, x_spec),
         out_specs=(x_spec, P()))
     return fn(p, x)

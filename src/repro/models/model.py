@@ -669,7 +669,6 @@ def _prefill_attn_layer_batched_cp(p: Dict, cfg: ModelConfig, h: jax.Array,
     tail tokens (key index > every real query position) and trimmed after.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.models.common import shard_map_compat
     m = pm.model_axis
     n = pm.model_size
     B, T, _ = h.shape
@@ -695,9 +694,8 @@ def _prefill_attn_layer_batched_cp(p: Dict, cfg: ModelConfig, h: jax.Array,
 
     seq4 = P(None, m, None, None)
     rep4 = P(None, None, None, None)
-    fn = shard_map_compat(body, mesh=pm.mesh,
-                          in_specs=(seq4, rep4, rep4, P()),
-                          out_specs=seq4)
+    fn = jax.shard_map(body, check_vma=False, mesh=pm.mesh,
+                       in_specs=(seq4, rep4, rep4, P()), out_specs=seq4)
     o = pm.replicate(fn(q, k_all, v_all, jnp.asarray(q_offset, jnp.int32)))
     x = hp + o.reshape(B, T + pad, -1) @ p["attn"]["wo"]
     if pad:

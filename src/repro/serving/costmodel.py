@@ -1,8 +1,9 @@
 """Analytic cost model for iteration latencies and transfer times.
 
 The paper's wall-clock figures come from an A100-40GB + PCIe Gen4 testbed;
-this container is CPU-only, so the discrete-event simulator replays the
-paper's experiments against this calibrated model instead.  Default
+the discrete-event simulator replays the paper's experiments against this
+calibrated model, and the engine charges it as its clock on CPU (on an
+accelerator the engine measures wall time instead).  Default
 constants are the A100 testbed (to reproduce the paper's numbers); a TPU
 v5e preset is provided for the deployment target.
 

@@ -46,10 +46,12 @@ end-to-end.
 The CONTROL PLANE is fully real (scheduling, admission, caching, transfer
 accounting, prefill segmentation); the MODEL COMPUTE is fully real (actual
 forward passes, actual DSA block selections feeding the working-set
-estimator).  Iteration LATENCY is charged from the analytic cost model,
-because this container has no TPU — wall-clock on CPU would measure the
-wrong machine.  Set ``charge_real_time=True`` to use wall clock instead
-(useful for relative comparisons in tests).
+estimator).  The engine clock is decided by the platform: on an
+accelerator each iteration is charged its measured wall time, ending once
+the device has finished the iteration's work; on CPU it is charged from
+the analytic cost model, because CPU wall time measures the wrong machine.
+``charge_real_time`` overrides the choice (tests use wall clock on CPU for
+relative comparisons).
 
 The engine is what `examples/serve_longcontext.py` and the Fig. 8 / Fig. 16
 benchmarks drive.
@@ -113,7 +115,9 @@ class EngineConfig:
     hbm_budget_bytes: int = 1 << 30          # HBM KV-cache budget (M_avl)
     hbm_blocks_per_request: int = 96         # per-request LRU capacity
     attn_impl: str = "ref"                   # "ref" | "kernel"
-    charge_real_time: bool = False
+    charge_real_time: Optional[bool] = None  # None: measured wall time on
+                                             # an accelerator, cost model
+                                             # on CPU (resolved per engine)
     greedy: bool = True
     seed: int = 0
     batched_decode: bool = True              # ONE decode_step per iteration
@@ -322,6 +326,12 @@ class ServingEngine:
                 "drop_evicted_device_blocks only acts on a device plane "
                 "(batched_decode=True, decode_plane='staged' or "
                 "'persistent')")
+        if eng.charge_real_time is None:
+            # the clock follows the platform; resolve into a COPY (same
+            # rationale as the knobs above)
+            eng = dataclasses.replace(
+                eng, charge_real_time=jax.default_backend() != "cpu")
+            self.eng = eng
         if eng.obs is None:
             # env opt-in so benches/CI can trace without touching configs;
             # resolve into a COPY (same rationale as the knobs above)
@@ -1739,8 +1749,9 @@ class ServingEngine:
         attention) -> attend.  The fused planes run one forward and do
         write-back + selection accounting afterwards.
 
-        Time is charged from the analytic cost model in engine-clock
-        seconds (``charge_real_time=True`` uses wall clock); transfer stats
+        Time is charged in engine-clock seconds: measured wall time up to
+        the device finishing the iteration (``charge_real_time``, the
+        accelerator default) or the analytic cost model; transfer stats
         are in bytes/calls/blocks with each moved block counted exactly
         once (see ``KVCacheManager``)."""
         self._admit_arrivals()
@@ -1875,6 +1886,7 @@ class ServingEngine:
 
         # --- charge time -------------------------------------------------
         if self.eng.charge_real_time:
+            self._wait_device()
             t_iter = time.perf_counter() - t0
         else:
             attended = min(self.cfg.dsa.token_budget, 1 << 30) \
@@ -1961,6 +1973,15 @@ class ServingEngine:
                 decode_rows=len(plan.decode_reqs),
                 prefill_rows=len(plan.prefill_reqs))
         return plan
+
+    def _wait_device(self) -> None:
+        """Block until the device has finished everything the iteration
+        dispatched.  Sampling already read the logits of rows that
+        produced a token; prefill rows parked mid-walk may still have
+        work queued on their plane's residual stream and decode pools."""
+        jax.block_until_ready(
+            [p.state for p in self.planes.values()]
+            + [p.hidden for p in self.prefill_planes.values()])
 
     def run(self, max_iters: int = 10_000) -> ServingMetrics:
         """Step until idle (every submitted request finished) or

@@ -13,16 +13,6 @@ from repro.configs import get_smoke_config
 from repro.models import model as M
 
 
-def make_abstract_mesh(sizes, names):
-    """jax.sharding.AbstractMesh across jax versions: new API takes
-    (axis_sizes, axis_names); 0.4.x takes ((name, size), ...)."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
 @pytest.fixture(scope="session")
 def tiny_cfg(smoke_setup):
     return smoke_setup("qwen2-0.5b")[0]

@@ -151,3 +151,17 @@ def test_engine_on_nontrivial_arch_families(arch, smoke_setup):
     m = eng.run()
     assert m.num_finished == 1
     assert len(eng.states[r.req_id].out_tokens) == 4
+
+
+def test_engine_clock_follows_the_platform(setup, monkeypatch):
+    """Measured wall time on an accelerator, the cost model on CPU; the
+    caller's config is resolved into a copy, never mutated."""
+    cfg, params = setup
+    ec = EngineConfig()
+    assert ServingEngine(params, cfg, ec).eng.charge_real_time is False
+    assert ec.charge_real_time is None
+    assert ServingEngine(params, cfg, EngineConfig(
+        charge_real_time=True)).eng.charge_real_time is True
+    import repro.serving.engine as engine_mod
+    monkeypatch.setattr(engine_mod.jax, "default_backend", lambda: "tpu")
+    assert ServingEngine(params, cfg, ec).eng.charge_real_time is True

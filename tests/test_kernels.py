@@ -182,3 +182,19 @@ def test_flash_prefill_q_offset():
     want = ref.flash_prefill(q, k, v, q_offset=48)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# interpret vs Mosaic: decided by the platform
+# ---------------------------------------------------------------------------
+
+def test_interpret_mode_follows_the_platform(monkeypatch):
+    from repro.kernels import platform
+    assert platform.resolve_interpret() is (jax.default_backend() == "cpu")
+    assert platform.resolve_interpret(False) is False
+    assert platform.resolve_interpret(True) is True
+    monkeypatch.setattr(platform.jax, "default_backend", lambda: "tpu")
+    assert platform.resolve_interpret() is False
+    monkeypatch.setattr(platform.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(NotImplementedError, match="gpu"):
+        platform.resolve_interpret()

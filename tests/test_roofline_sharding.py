@@ -3,9 +3,8 @@ AbstractMesh supplies axis names/sizes)."""
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from conftest import make_abstract_mesh
 from repro.roofline.analysis import (_shape_bytes, collective_bytes_from_hlo, model_flops)
 
 
@@ -80,12 +79,12 @@ def test_model_flops_train_is_6nd(tiny_cfg):
 
 @pytest.fixture(scope="module")
 def mesh():
-    return make_abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 @pytest.fixture(scope="module")
 def pod_mesh():
-    return make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def specs_of(tree):

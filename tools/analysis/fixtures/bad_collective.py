@@ -13,18 +13,17 @@ def build_stages():
     from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.core import plane_contract as pc
-    from repro.models.common import shard_map_compat
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("model",))
 
     def attend(x):
-        body = shard_map_compat(
+        body = jax.shard_map(
             lambda x: jax.lax.psum(x, "model"),     # contract: no comm
-            mesh=mesh, in_specs=P(), out_specs=P())
+            check_vma=False, mesh=mesh, in_specs=P(), out_specs=P())
         return body(x)
 
     args = (jax.ShapeDtypeStruct((8, 16), jnp.float32),)
     return [pc.StageLowering(
         stage="attend[fixture:heads]", fn=attend, args=args,
         rules=pc.sharding_rules("attend", "heads"),
-        file=__file__, line=21)]
+        file=__file__, line=20)]

@@ -13,18 +13,18 @@ def build_stages():
     from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.core import plane_contract as pc
-    from repro.models.common import shard_map_compat
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("model",))
 
     def attend(x):
-        body = shard_map_compat(
+        body = jax.shard_map(
             lambda x: x * 2.0,
-            mesh=mesh, in_specs=P("model"), out_specs=P("model"))
+            check_vma=False, mesh=mesh, in_specs=P("model"),
+            out_specs=P("model"))
         return body(x) + 1.0                    # leaks the sharded spec
 
     args = (jax.ShapeDtypeStruct((8, 16), jnp.float32),)
     return [pc.StageLowering(
         stage="attend[fixture:heads]", fn=attend, args=args,
         rules=pc.sharding_rules("attend", "heads"),
-        file=__file__, line=20)]
+        file=__file__, line=19)]

@@ -45,14 +45,14 @@ def build_stages():
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from repro.core import plane_contract as pc
-    from repro.models.common import shard_map_compat
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("model",))
 
     def attend(x):
-        body = shard_map_compat(
+        body = jax.shard_map(
             lambda x: x * 2.0,
-            mesh=mesh, in_specs=P("model"), out_specs=P("model"))
+            check_vma=False, mesh=mesh, in_specs=P("model"),
+            out_specs=P("model"))
         rep = NamedSharding(mesh, P())
         return jax.lax.with_sharding_constraint(body(x), rep)
 
@@ -60,4 +60,4 @@ def build_stages():
     return [pc.StageLowering(
         stage="attend[fixture:heads]", fn=attend, args=args,
         rules=pc.sharding_rules("attend", "heads"),
-        file=__file__, line=52)]
+        file=__file__, line=51)]

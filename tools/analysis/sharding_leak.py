@@ -158,9 +158,8 @@ def _tainted_outvars(jaxpr) -> set:
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
         if name == "shard_map":
-            out_names = eqn.params.get("out_names", ())
-            for var, names in zip(eqn.outvars, out_names):
-                if names:                       # non-empty spec => sharded
+            for var, spec in zip(eqn.outvars, eqn.params["out_specs"]):
+                if any(e is not None for e in spec):    # sharded spec
                     tainted.add(var)
         elif name == "sharding_constraint":
             if _is_replicated_sharding(eqn.params.get("sharding")):
