@@ -48,7 +48,6 @@ from __future__ import annotations
 import bisect
 import dataclasses
 import os
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -56,7 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import model as M
-from repro.obs.tracing import NULL_TRACER
+from repro.obs import tracing
 
 # Route row-slot block movement through the Pallas kernels
 # (kernels/gather_blocks.py / scatter_blocks.py, _hkv variants).  Default is
@@ -124,9 +123,14 @@ class StageFns:
     (``abstract_args``, a ShapeDtypeStruct pytree) — what the plane
     sharding-leak pass (tools/analysis, docs/architecture.md §8) lowers
     via ``jax.make_jaxpr`` to check collectives and out-spec replication
-    against ``repro.core.plane_contract.sharding_rules``."""
+    against ``repro.core.plane_contract.sharding_rules``.
+
+    Each stage's program is named ``<jit_prefix>_<stage>`` (the device
+    trace shows ``jit_decode_select``, ``jit_prefill_attn``, ...), and each
+    launch opens the span ``stage.<stage>`` around the dispatch."""
 
     contract_protocol = "stage-registry"
+    jit_prefix = "stage"
 
     def __init__(self):
         self.trace_count = 0
@@ -147,21 +151,26 @@ class StageFns:
         def fn(*args):
             self.trace_count += 1           # trace-time side effect only
             return f(*args)
+        fn.__name__ = fn.__qualname__ = (
+            f"{self.jit_prefix}_{stage}".replace("-", "_"))
         jitted = jax.jit(fn,
                          donate_argnums=donate if self._donate_ok else ())
+        span_name = "stage." + stage
 
         def call(*args):
-            self.calls += 1
-            leaves, treedef = jax.tree.flatten(args)
-            self.shape_signatures.add(
-                (stage, str(treedef),
-                 tuple((tuple(jnp.shape(leaf)), str(jnp.result_type(leaf)))
-                       for leaf in leaves)))
-            if stage not in self.abstract_args:
-                self.abstract_args[stage] = jax.tree.map(
-                    lambda leaf: jax.ShapeDtypeStruct(
-                        jnp.shape(leaf), jnp.result_type(leaf)), args)
-            return jitted(*args)
+            with tracing.DEFAULT.span(span_name):
+                self.calls += 1
+                leaves, treedef = jax.tree.flatten(args)
+                self.shape_signatures.add(
+                    (stage, str(treedef),
+                     tuple((tuple(jnp.shape(leaf)),
+                            str(jnp.result_type(leaf)))
+                           for leaf in leaves)))
+                if stage not in self.abstract_args:
+                    self.abstract_args[stage] = jax.tree.map(
+                        lambda leaf: jax.ShapeDtypeStruct(
+                            jnp.shape(leaf), jnp.result_type(leaf)), args)
+                return jitted(*args)
         return call
 
 
@@ -234,6 +243,7 @@ class _StagedDecodeFns(StageFns):
     """
 
     contract_protocol = "staged-decode"
+    jit_prefix = "decode"
 
     def __init__(self, cfg, attn_impl: str, plane_mesh=None):
         super().__init__()
@@ -370,13 +380,11 @@ class DevicePoolPlane:
                                          # buffers to host
         self.stage_timeline: List[Tuple[int, float, float]] = []
         # last iteration's (layer, idx_sync_s, host_stage_s) per stage_cb
-        self.dispatch_sync_s = 0.0       # accumulated idx-sync time, all
-        self.host_stage_s = 0.0          # iterations; the counter half of
-                                         # the overlap cross-check (the
-                                         # trace half reuses the same
-                                         # perf_counter reads)
-        self.tracer = NULL_TRACER        # engine swaps in a live Tracer
-                                         # when EngineConfig.obs is on
+        self.dispatch_sync_s = 0.0       # accumulated idx-sync and host-
+        self.host_stage_s = 0.0          # stage time, all iterations: the
+                                         # plane.idx_sync / plane.host_stage
+                                         # spans' own clock reads
+        self.tracer = tracing.DEFAULT    # the engine hands its own
         # per-layer param slices for the staged pipeline, cached per params
         # OBJECT (the entry's strong ref keeps the id() stable).  Lives on
         # the plane — not the process-global _StagedDecodeFns — so retired
@@ -460,40 +468,41 @@ class DevicePoolPlane:
         """Copy one request's DecodeState (B=1, list-mode caches) into a
         free batch row; returns the row.  The ONLY full-pool copy in a
         request's decode lifetime."""
-        if req_id in self.rows:
-            raise ValueError(f"{req_id} already admitted")
-        if not isinstance(state["caches"], list):
-            raise ValueError("DevicePoolPlane requires list-mode caches")
-        if int(state["cur_len"].shape[0]) != 1:
-            raise ValueError("admit expects a single-request state (B=1)")
-        nbs = [c["k"].shape[2] if M.is_pool_cache(c) else None
-               for c in state["caches"]]
-        nb_req = max((n for n in nbs if n is not None), default=0)
-        self._ensure_capacity(state, len(self.rows) + 1, nb_req)
-        row = self._free.pop(0)
-        if row in self._ever_used:
-            self.rows_reused += 1
-        self._ever_used.add(row)
-        st = self.state
-        for l, c in enumerate(state["caches"]):
-            if M.is_pool_cache(c):
-                for key, v in c.items():
-                    st["caches"][l][key] = \
-                        st["caches"][l][key].at[row, :, :nbs[l]].set(v[0])
-            else:
-                st["caches"][l] = jax.tree.map(
+        with self.tracer.span("plane.admit", req=req_id):
+            if req_id in self.rows:
+                raise ValueError(f"{req_id} already admitted")
+            if not isinstance(state["caches"], list):
+                raise ValueError("DevicePoolPlane requires list-mode caches")
+            if int(state["cur_len"].shape[0]) != 1:
+                raise ValueError("admit expects a single-request state (B=1)")
+            nbs = [c["k"].shape[2] if M.is_pool_cache(c) else None
+                   for c in state["caches"]]
+            nb_req = max((n for n in nbs if n is not None), default=0)
+            self._ensure_capacity(state, len(self.rows) + 1, nb_req)
+            row = self._free.pop(0)
+            if row in self._ever_used:
+                self.rows_reused += 1
+            self._ever_used.add(row)
+            st = self.state
+            for l, c in enumerate(state["caches"]):
+                if M.is_pool_cache(c):
+                    for key, v in c.items():
+                        st["caches"][l][key] = \
+                            st["caches"][l][key].at[row, :, :nbs[l]].set(v[0])
+                else:
+                    st["caches"][l] = jax.tree.map(
+                        lambda dst, src: dst.at[row].set(src[0]),
+                        st["caches"][l], c)
+            st["cur_len"] = st["cur_len"].at[row].set(state["cur_len"][0])
+            if state["extra"]:
+                st["extra"] = jax.tree.map(
                     lambda dst, src: dst.at[row].set(src[0]),
-                    st["caches"][l], c)
-        st["cur_len"] = st["cur_len"].at[row].set(state["cur_len"][0])
-        if state["extra"]:
-            st["extra"] = jax.tree.map(
-                lambda dst, src: dst.at[row].set(src[0]),
-                st["extra"], state["extra"])
-        self.rows[req_id] = row
-        self.row_layout[req_id] = nbs
-        self.cur_host[req_id] = int(state["cur_len"][0])
-        self.admits += 1
-        return row
+                    st["extra"], state["extra"])
+            self.rows[req_id] = row
+            self.row_layout[req_id] = nbs
+            self.cur_host[req_id] = int(state["cur_len"][0])
+            self.admits += 1
+            return row
 
     def release(self, req_id: str) -> int:
         """Free a finished request's row (device slots become reusable —
@@ -585,12 +594,8 @@ class DevicePoolPlane:
                     layer_params[i], x, st["caches"][i], mask)
                 st["caches"][i] = new_cache
                 continue
-            if tr.enabled:
-                _ts = time.perf_counter()
             q, new_cache, idx, valid = fns.select(
                 layer_params[i], x, st["caches"][i], st["cur_len"], mask)
-            if tr.enabled:
-                tr.end("select", "stage", _ts, layer=i)
             st["caches"][i] = new_cache
             if idx is not None:
                 info["selected"][i] = idx
@@ -603,29 +608,17 @@ class DevicePoolPlane:
                 # selections to stage.  In async mode the callback must
                 # not block on the device again (plane-contract rule
                 # no-sync-in-dispatch-window); the wall-clock split
-                # between the idx sync and the host stage is recorded so
-                # bench_overlap can report ACHIEVED overlap.
-                t0 = time.perf_counter()
-                sel = None if idx is None else np.asarray(idx)
-                t1 = time.perf_counter()
-                stage_cb(i, sel, prev)
-                t2 = time.perf_counter()
-                timeline.append((i, t1 - t0, t2 - t1))
-                if tr.enabled:
-                    # the spans reuse t0/t1/t2 verbatim — the trace and
-                    # the dispatch_sync_s/host_stage_s counters are the
-                    # same measurement exported two ways
-                    tr.complete_at("idx-sync", "stage", t0, t1 - t0,
-                                   layer=i)
-                    tr.complete_at("host-stage", "host-stage", t1,
-                                   t2 - t1, layer=i)
-            if tr.enabled:
-                _ts = time.perf_counter()
+                # between the idx sync and the host stage (the two spans'
+                # durations) is recorded so bench_overlap can report
+                # ACHIEVED overlap.
+                with tr.span("plane.idx_sync", layer=i) as s_sync:
+                    sel = None if idx is None else np.asarray(idx)
+                with tr.span("plane.host_stage", layer=i) as s_stage:
+                    stage_cb(i, sel, prev)
+                timeline.append((i, s_sync.dur, s_stage.dur))
             x = fns.attend(layer_params[i], x, q, st["caches"][i],
                            st["cur_len"], idx, valid,
                            M.index_enc_kvs(enc_kvs, i))
-            if tr.enabled:
-                tr.end("attend", "stage", _ts, layer=i)
         logits, new_len = fns.logits(params, x, st["cur_len"], mask)
         st["cur_len"] = new_len
         self.stage_timeline = timeline
@@ -671,20 +664,22 @@ class DevicePoolPlane:
         semantics guarantee the queued gather reads the pool value as of
         dispatch, so later pool-updating stages cannot corrupt the stripe
         even though they reuse (donated) pool buffers."""
-        bs = self.cfg.dsa.block_size
-        rows = jnp.asarray([self.rows[r] for r in req_ids], jnp.int32)
-        pos = np.asarray([prev_lens[r] for r in req_ids], np.int64)
-        blk = jnp.asarray(pos // bs, jnp.int32)
-        slot = jnp.asarray(pos % bs, jnp.int32)
-        out: Dict[int, Tuple[jax.Array, Optional[jax.Array]]] = {}
-        for l in (self.pool_layers() if layers is None else layers):
-            c = self.state["caches"][l]
-            k = c["k"][rows, :, blk, slot]                    # (R, Hkv, D)
-            v = c["v"][rows, :, blk, slot] if "v" in c else None
-            self.d2h_readback_bytes += k.nbytes + (
-                0 if v is None else v.nbytes)
-            out[l] = (k, v)
-        return out
+        with self.tracer.span("plane.kv_readback",
+                              layer=-1 if layers is None else layers[0]):
+            bs = self.cfg.dsa.block_size
+            rows = jnp.asarray([self.rows[r] for r in req_ids], jnp.int32)
+            pos = np.asarray([prev_lens[r] for r in req_ids], np.int64)
+            blk = jnp.asarray(pos // bs, jnp.int32)
+            slot = jnp.asarray(pos % bs, jnp.int32)
+            out: Dict[int, Tuple[jax.Array, Optional[jax.Array]]] = {}
+            for l in (self.pool_layers() if layers is None else layers):
+                c = self.state["caches"][l]
+                k = c["k"][rows, :, blk, slot]                    # (R, Hkv, D)
+                v = c["v"][rows, :, blk, slot] if "v" in c else None
+                self.d2h_readback_bytes += k.nbytes + (
+                    0 if v is None else v.nbytes)
+                out[l] = (k, v)
+            return out
 
     def restore_blocks(self, req_id: str, layer: int, blocks: List[int],
                        k_host: np.ndarray,
@@ -707,47 +702,59 @@ class DevicePoolPlane:
         select and attend stages (staged plane) — i.e. BEFORE the attention
         that selected the blocks — and is counted separately so the
         restore-ordering rate is observable (bench_overlap)."""
+        tr = self.tracer
         c = self.state["caches"][layer]
         H = c["k"].shape[1]
-        rows_l: List[int] = []
-        blks_l: List[int] = []
-        ks: List[np.ndarray] = []
-        vs: List[np.ndarray] = []
         has_v = "v" in c
-        for req_id, (blocks, k_host, v_host) in payload_by_req.items():
-            row = self.rows[req_id]
-            rows_l.extend([row] * len(blocks))
-            blks_l.extend(blocks)
-            # MLA: the host pool broadcasts the single latent head over
-            # geom.num_kv_heads; the device pool keeps one — use the first
-            ks.append(np.asarray(k_host)[:H])
-            if has_v and v_host is not None:
-                vs.append(np.asarray(v_host)[:H])
-        if not blks_l:
-            return
-        if USE_PALLAS_PLANE:
-            # kernel-demonstration route: per-row Pallas scatters
-            for req_id, (blocks, k_host, v_host) in payload_by_req.items():
-                row = self.rows[req_id]
-                c["k"] = scatter_row_blocks(c["k"], row, blocks,
-                                            jnp.asarray(k_host[:H]))
-                if has_v and v_host is not None:
-                    c["v"] = scatter_row_blocks(c["v"], row, blocks,
-                                                jnp.asarray(v_host[:H]))
-            self.blocks_restored += len(blks_l)
-            if before_use:
-                self.blocks_restored_before_use += len(blks_l)
-            return
-        rows = jnp.asarray(rows_l, jnp.int32)
-        blks = jnp.asarray(blks_l, jnp.int32)
-        # (Hkv, K_total, bs, D) -> (K_total, Hkv, bs, D): advanced indices
-        # at axes 0 and 2 put the gathered axis first in the update shape
-        k_all = jnp.asarray(np.concatenate(ks, axis=1).transpose(1, 0, 2, 3))
-        c["k"] = c["k"].at[rows, :, blks].set(k_all.astype(c["k"].dtype))
-        if has_v and vs:
-            v_all = jnp.asarray(
-                np.concatenate(vs, axis=1).transpose(1, 0, 2, 3))
-            c["v"] = c["v"].at[rows, :, blks].set(v_all.astype(c["v"].dtype))
+        with tr.span("plane.restore", layer=layer) as sp:
+            with tr.span("plane.restore.prepare"):
+                # host concatenation and the host-to-device copies
+                rows_l: List[int] = []
+                blks_l: List[int] = []
+                ks: List[np.ndarray] = []
+                vs: List[np.ndarray] = []
+                for req_id, (blocks, k_host, v_host) in \
+                        payload_by_req.items():
+                    row = self.rows[req_id]
+                    rows_l.extend([row] * len(blocks))
+                    blks_l.extend(blocks)
+                    # MLA: the host pool broadcasts the single latent head
+                    # over geom.num_kv_heads; the device pool keeps one —
+                    # use the first
+                    ks.append(np.asarray(k_host)[:H])
+                    if has_v and v_host is not None:
+                        vs.append(np.asarray(v_host)[:H])
+                if not blks_l:
+                    return
+                sp.set(blocks=len(blks_l))
+                if not USE_PALLAS_PLANE:
+                    rows = jnp.asarray(rows_l, jnp.int32)
+                    blks = jnp.asarray(blks_l, jnp.int32)
+                    # (Hkv, K_total, bs, D) -> (K_total, Hkv, bs, D):
+                    # advanced indices at axes 0 and 2 put the gathered
+                    # axis first in the update shape
+                    k_all = jnp.asarray(
+                        np.concatenate(ks, axis=1).transpose(1, 0, 2, 3))
+                    v_all = (jnp.asarray(np.concatenate(vs, axis=1)
+                                         .transpose(1, 0, 2, 3))
+                             if has_v and vs else None)
+            with tr.span("plane.restore.scatter"):
+                if USE_PALLAS_PLANE:
+                    # kernel-demonstration route: per-row Pallas scatters
+                    for req_id, (blocks, k_host, v_host) in \
+                            payload_by_req.items():
+                        row = self.rows[req_id]
+                        c["k"] = scatter_row_blocks(c["k"], row, blocks,
+                                                    jnp.asarray(k_host[:H]))
+                        if has_v and v_host is not None:
+                            c["v"] = scatter_row_blocks(
+                                c["v"], row, blocks, jnp.asarray(v_host[:H]))
+                else:
+                    c["k"] = c["k"].at[rows, :, blks].set(
+                        k_all.astype(c["k"].dtype))
+                    if v_all is not None:
+                        c["v"] = c["v"].at[rows, :, blks].set(
+                            v_all.astype(c["v"].dtype))
         self.blocks_restored += len(blks_l)
         if before_use:
             self.blocks_restored_before_use += len(blks_l)
@@ -757,14 +764,19 @@ class DevicePoolPlane:
         """Zero evicted blocks' device data (HBM eviction -> device memory
         actually dropped).  Block METADATA is kept resident so DSA scoring
         stays exact; re-selected blocks come back via ``restore_blocks``."""
+        tr = self.tracer
         row = self.rows[req_id]
         c = self.state["caches"][layer]
-        idx = jnp.asarray(blocks, jnp.int32)
-        zero = jnp.zeros((c["k"].shape[1], len(blocks)) + c["k"].shape[3:],
-                         c["k"].dtype)
-        c["k"] = scatter_row_blocks(c["k"], row, idx, zero)
-        if "v" in c:
-            c["v"] = scatter_row_blocks(c["v"], row, idx, zero)
+        with tr.span("plane.drop", req=req_id, layer=layer,
+                     blocks=len(blocks)):
+            with tr.span("plane.drop.prepare"):
+                idx = jnp.asarray(blocks, jnp.int32)
+                zero = jnp.zeros((c["k"].shape[1], len(blocks))
+                                 + c["k"].shape[3:], c["k"].dtype)
+            with tr.span("plane.drop.scatter"):
+                c["k"] = scatter_row_blocks(c["k"], row, idx, zero)
+                if "v" in c:
+                    c["v"] = scatter_row_blocks(c["v"], row, idx, zero)
         self.blocks_dropped += len(blocks)
 
     # -- introspection -----------------------------------------------------

@@ -42,10 +42,9 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Any, Callable, Dict, Optional
 
-from repro.obs.tracing import NULL_TRACER
+from repro.obs import tracing
 
 
 class HostStageError(RuntimeError):
@@ -78,7 +77,8 @@ class HostStageWorker:
         self._closed = False
         self.jobs_run = 0
         self.busy_s = 0.0                      # total time inside job fns
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+                                               # (the worker.job spans)
+        self.tracer = tracer if tracer is not None else tracing.DEFAULT
         self._thread = threading.Thread(target=self._loop, name=name,
                                         daemon=True)
         self._thread.start()
@@ -91,17 +91,10 @@ class HostStageWorker:
                 return
             try:
                 if self._exc is None:          # fail fast after first error
-                    t0 = time.perf_counter()
-                    job.fn(*job.args)
-                    dt = time.perf_counter() - t0
-                    self.busy_s += dt
+                    with self.tracer.span("worker.job", key=job.key) as sp:
+                        job.fn(*job.args)
+                    self.busy_s += sp.dur
                     self.jobs_run += 1
-                    tr = self.tracer
-                    if tr.enabled:
-                        # same t0/dt as busy_s, so the trace and counter
-                        # overlap instruments cannot drift on one run
-                        tr.complete_at("host-stage", "host-stage-worker",
-                                       t0, dt, key=job.key)
             except BaseException as e:         # noqa: BLE001 - re-raised
                 self._exc = e                  # on the dispatch thread
             finally:

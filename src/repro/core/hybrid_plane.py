@@ -46,7 +46,6 @@ the cache-hit invariant of both underlying registries covers it.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -59,7 +58,7 @@ from repro.core.prefill_plane import (PrefillGroupRun,
                                       PrefillIterationResult, PrefillPlane,
                                       PrefillWalk, prefill_fns_for)
 from repro.models import model as M
-from repro.obs.tracing import NULL_TRACER
+from repro.obs import tracing
 
 
 class _HybridFns:
@@ -161,11 +160,10 @@ class HybridPlane:
         self.iterations = 0
         self.stage_timeline: List[Tuple[int, float, float]] = []
         # last iteration's (layer, idx_sync_s, host_stage_s) per layer_cb
-        self.dispatch_sync_s = 0.0    # accumulated across iterations —
-        self.host_stage_s = 0.0       # counter half of the overlap
-                                      # cross-check (see device_pool)
-        self.tracer = NULL_TRACER     # engine installs a live Tracer
-                                      # when EngineConfig.obs is on
+        self.dispatch_sync_s = 0.0    # accumulated across iterations
+        self.host_stage_s = 0.0       # from the plane.idx_sync /
+                                      # plane.host_stage spans
+        self.tracer = tracing.DEFAULT  # the engine hands its own
 
     def run_iteration(self, params: Dict, decode_jobs: List[DecodeJob],
                       prefill_jobs: List[PrefillJob],
@@ -203,8 +201,6 @@ class HybridPlane:
             selections: List[Tuple[DecodeRun, Optional[np.ndarray]]] = []
             t_sync = 0.0
             if kind == "attn":
-                if tr.enabled and dec:
-                    _ts = time.perf_counter()
                 for d in dec:
                     st = d.plane.state
                     q, new_cache, idx, valid = d.fns.select(
@@ -217,13 +213,10 @@ class HybridPlane:
                     # np.asarray(idx) is the ONLY host sync per layer (same
                     # as step_staged): it forces select_i — and the still-
                     # queued attend_{i-1} — before the host stage runs
-                    t0 = time.perf_counter()
-                    selections.append(
-                        (d, None if idx is None else np.asarray(idx)))
-                    t_sync += time.perf_counter() - t0
-                if tr.enabled and dec:
-                    tr.end("select", "stage", _ts, layer=i,
-                           planes=len(dec))
+                    with tr.span("plane.idx_sync", layer=i) as s_sync:
+                        selections.append(
+                            (d, None if idx is None else np.asarray(idx)))
+                    t_sync += s_sync.dur
             else:
                 for d in dec:
                     st = d.plane.state
@@ -235,30 +228,19 @@ class HybridPlane:
                 for g in plane.run_layer(params, i, walk):
                     layer_groups.append((plane, g))
             if layer_cb is not None and (selections or layer_groups):
-                t1 = time.perf_counter()
-                layer_cb(LayerWindow(layer=i, kind=kind,
-                                     selections=selections,
-                                     groups=layer_groups))
-                t2 = time.perf_counter()
-                timeline.append((i, t_sync, t2 - t1))
-                if tr.enabled:
-                    # same t1/t2 as the timeline entry: trace and counter
-                    # overlap instruments share the measurement
-                    tr.complete_at("host-stage", "host-stage", t1,
-                                   t2 - t1, layer=i,
-                                   groups=len(layer_groups))
+                with tr.span("plane.host_stage", layer=i,
+                             groups=len(layer_groups)) as s_stage:
+                    layer_cb(LayerWindow(layer=i, kind=kind,
+                                         selections=selections,
+                                         groups=layer_groups))
+                timeline.append((i, t_sync, s_stage.dur))
             if kind == "attn":
-                if tr.enabled and dec:
-                    _ts = time.perf_counter()
                 for d in dec:
                     st = d.plane.state
                     d.x = d.fns.attend(d.layer_params[i], d.x, d.q,
                                        st["caches"][i], st["cur_len"],
                                        d.idx, d.valid,
                                        M.index_enc_kvs(d.enc_kvs, i))
-                if tr.enabled and dec:
-                    tr.end("attend", "stage", _ts, layer=i,
-                           planes=len(dec))
         self.stage_timeline = timeline
         for _, _sync_s, _stage_s in timeline:
             self.dispatch_sync_s += _sync_s

@@ -16,12 +16,11 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.obs.tracing import NULL_TRACER
+from repro.obs import tracing
 
 
 @dataclasses.dataclass
@@ -441,8 +440,7 @@ class KVCacheManager:
         self.pools: Dict[str, HostPool] = {}
         self._retired_stats = TransferStats()   # stats of released requests
         self.fused_stats = TransferStats()      # batched FlashH2D launches
-        self.tracer = NULL_TRACER               # engine installs a live
-                                                # Tracer when obs is on
+        self.tracer = tracing.DEFAULT           # the engine hands its own
 
     # -- lifecycle ---------------------------------------------------------
     def register(self, req_id: str, max_tokens: int,
@@ -481,18 +479,19 @@ class KVCacheManager:
         request's LRU evicted during these accesses (``pop_evicted``; empty
         lists otherwise).  Requests without a registered cache are skipped.
         """
-        missing_by_req: Dict[str, List[int]] = {}
-        evicted_by_req: Dict[str, List[Tuple[int, int]]] = {}
-        for req_id, blocks in blocks_by_req.items():
-            cache = self.caches.get(req_id)
-            if cache is None:
-                continue
-            missing = cache.access(layer, blocks)
-            if missing:
-                missing_by_req[req_id] = missing
-            if drain_evicted:
-                evicted_by_req[req_id] = cache.pop_evicted()
-        return missing_by_req, evicted_by_req
+        with self.tracer.span("kv.lru", layer=layer):
+            missing_by_req: Dict[str, List[int]] = {}
+            evicted_by_req: Dict[str, List[Tuple[int, int]]] = {}
+            for req_id, blocks in blocks_by_req.items():
+                cache = self.caches.get(req_id)
+                if cache is None:
+                    continue
+                missing = cache.access(layer, blocks)
+                if missing:
+                    missing_by_req[req_id] = missing
+                if drain_evicted:
+                    evicted_by_req[req_id] = cache.pop_evicted()
+            return missing_by_req, evicted_by_req
 
     # -- data plane --------------------------------------------------------
     def load_blocks_fused(self, layer: int,
@@ -519,29 +518,24 @@ class KVCacheManager:
         under the persistent decode plane the engine scatters these
         payloads DIRECTLY into the requests' device slots
         (``DevicePoolPlane.restore_blocks``)."""
-        tr = self.tracer
-        if tr.enabled:
-            _ts = time.perf_counter()
-        out: Dict[str, Tuple[np.ndarray, Optional[np.ndarray]]] = {}
-        total_blocks = 0
-        total_bytes = 0
-        for req_id, blocks in blocks_by_req.items():
-            pool = self.pools.get(req_id)
-            if pool is None or not blocks:
-                continue
-            k, v = pool.gather(layer, blocks)
-            out[req_id] = (k, v)
-            total_blocks += len(blocks) * self.geom.num_kv_heads
-            total_bytes += pool.wire_bytes(len(blocks))
-        if total_blocks:
-            self.fused_stats.h2d_calls += 1
-            self.fused_stats.h2d_blocks += total_blocks
-            self.fused_stats.h2d_bytes += total_bytes
-            if tr.enabled:
-                tr.end("FlashH2D", "transfer", _ts, layer=layer,
-                       blocks=total_blocks, bytes=total_bytes,
-                       fused_reqs=len(out))
-        return out
+        with self.tracer.span("kv.h2d_gather", layer=layer) as sp:
+            out: Dict[str, Tuple[np.ndarray, Optional[np.ndarray]]] = {}
+            total_blocks = 0
+            total_bytes = 0
+            for req_id, blocks in blocks_by_req.items():
+                pool = self.pools.get(req_id)
+                if pool is None or not blocks:
+                    continue
+                k, v = pool.gather(layer, blocks)
+                out[req_id] = (k, v)
+                total_blocks += len(blocks) * self.geom.num_kv_heads
+                total_bytes += pool.wire_bytes(len(blocks))
+            if total_blocks:
+                self.fused_stats.h2d_calls += 1
+                self.fused_stats.h2d_blocks += total_blocks
+                self.fused_stats.h2d_bytes += total_bytes
+                sp.set(blocks=total_blocks, bytes=total_bytes)
+            return out
 
     def save_new_tokens_fused(self, layer: int,
                               kv_by_req: Dict[str, Tuple[int, np.ndarray,
@@ -567,23 +561,19 @@ class KVCacheManager:
         dtype — dequantized at gather — so they stay safe to scatter
         straight into device slots.  Staged bytes are booked at wire size
         (see ``HostPool.stage``)."""
-        tr = self.tracer
-        if tr.enabled:
-            _ts = time.perf_counter()
-        total_bytes = 0
-        for req_id, (start, k, v) in kv_by_req.items():
-            pool = self.pools.get(req_id)
-            if pool is None:
-                continue
-            total_bytes += pool.stage(layer, start, k, v)
-        if total_bytes:
-            self.fused_stats.d2h_calls += 1
-            self.fused_stats.d2h_bytes += total_bytes
-            # in async mode this fires on the HostStageWorker thread —
-            # the tracer is thread-safe and books the span to that tid
-            if tr.enabled:
-                tr.end("FlashD2H", "transfer", _ts, layer=layer,
-                       bytes=total_bytes, fused_reqs=len(kv_by_req))
+        # in async mode this runs on the HostStageWorker thread, and the
+        # span lands on that thread's line of the trace
+        with self.tracer.span("kv.d2h_save", layer=layer) as sp:
+            total_bytes = 0
+            for req_id, (start, k, v) in kv_by_req.items():
+                pool = self.pools.get(req_id)
+                if pool is None:
+                    continue
+                total_bytes += pool.stage(layer, start, k, v)
+            if total_bytes:
+                self.fused_stats.d2h_calls += 1
+                self.fused_stats.d2h_bytes += total_bytes
+                sp.set(bytes=total_bytes)
 
     # -- accounting --------------------------------------------------------
     def hbm_used_bytes(self) -> int:

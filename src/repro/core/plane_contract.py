@@ -74,12 +74,12 @@ ALL_RULES = (
 )
 
 # Roots that identify an obs-layer object in source (pass 2's
-# no-obs-in-jit): a call like ``tracer.end(...)`` / ``self.metrics.inc()``
+# no-obs-in-jit): a call like ``tracer.span(...)`` / ``self.metrics.inc()``
 # inside a jitted stage body is a host side effect that fires once at
 # trace time and never again — spans silently vanish, counters undercount.
-# Instrumentation belongs in the drivers, around the stage launches.
+# Instrumentation belongs in the plane code, around the stage launches.
 OBS_ROOT_NAMES = frozenset({
-    "tracer", "_tracer", "obs", "_obs", "metrics", "_metrics",
+    "tracer", "_tracer", "tracing", "obs", "_obs", "metrics", "_metrics",
     "metrics_registry", "registry",
 })
 
@@ -153,13 +153,11 @@ EFFECT_OF_CALL: Dict[str, Tuple[str, str]] = {
     "asarray": ("sync", "host"),
     "block_until_ready": ("sync", "host"),
     "device_get": ("sync", "host"),
-    # blocking obs exports (file I/O / full-registry walks / event-list
-    # copies) — fine between iterations, forbidden inside an async
-    # dispatch window for the same reason (they re-serialize the overlap
-    # the pipeline exists to create).  Guarded `tracer.enabled` span
-    # emission is NOT in this table: it never blocks.
-    "dump_trace": ("sync", "obs"),
-    "chrome_trace": ("sync", "obs"),
+    # blocking obs exports (full-registry walks) — fine between
+    # iterations, forbidden inside an async dispatch window for the same
+    # reason (they re-serialize the overlap the pipeline exists to
+    # create).  Span emission (`tracer.span`) is NOT in this table: it
+    # never blocks.
     "metrics_snapshot": ("sync", "obs"),
     "metrics_prometheus": ("sync", "obs"),
     "prometheus_text": ("sync", "obs"),

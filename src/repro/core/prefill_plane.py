@@ -40,7 +40,6 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -50,7 +49,7 @@ import numpy as np
 from repro.core.device_pool import BucketingPolicy, StageFns
 from repro.core.layer_prefill import PrefillSegment
 from repro.models import model as M
-from repro.obs.tracing import NULL_TRACER
+from repro.obs import tracing
 
 
 class _PrefillFns(StageFns):
@@ -63,6 +62,7 @@ class _PrefillFns(StageFns):
     the iteration count)."""
 
     contract_protocol = "prefill-plane"
+    jit_prefix = "prefill"
 
     def __init__(self, cfg, plane_mesh=None):
         super().__init__()
@@ -117,6 +117,7 @@ class _AdmitEmbedFns(StageFns):
     grid, independent of how many requests arrive together."""
 
     contract_protocol = "admit-embed"
+    jit_prefix = "prefill"
 
     def __init__(self, cfg):
         super().__init__()
@@ -210,8 +211,7 @@ class PrefillPlane:
         self.finalize_launches = 0
         self.iterations = 0
         self.buckets_seen: set = set()      # (b_cap, chunk_cap) launched at
-        self.tracer = NULL_TRACER           # engine installs a live Tracer
-                                            # when EngineConfig.obs is on
+        self.tracer = tracing.DEFAULT       # the engine hands its own
 
     # -- params ------------------------------------------------------------
 
@@ -293,33 +293,34 @@ class PrefillPlane:
         into a free row, zero the row's recurrent states, and install its
         segment plan.  The only full-stream copy in the request's prefill
         lifetime."""
-        if req_id in self.rows:
-            raise ValueError(f"{req_id} already admitted")
-        S = int(h.shape[1])
-        self._ensure_capacity(len(self.rows) + 1, S, h)
-        row = self._free.pop(0)
-        if row in self._ever_used:
-            self.rows_reused += 1
-        self._ever_used.add(row)
-        self.hidden = self.hidden.at[row].set(0).at[row, :S].set(h[0])
-        self._tok_len = self._tok_len.at[row].set(S)
-        for l, s in enumerate(self.rec):
-            if s is not None:
-                self.rec[l] = jax.tree.map(lambda x: x.at[row].set(0), s)
-        if enc_kvs is not None:
-            if self.enc is None:
-                self.enc = [tuple(jnp.zeros((self.b_cap,) + a.shape[1:],
-                                            a.dtype) for a in kv)
-                            for kv in enc_kvs]
-            self.enc = [tuple(dst.at[row].set(src[0])
-                              for dst, src in zip(self.enc[l], enc_kvs[l]))
-                        for l in range(len(self.enc))]
-        self.rows[req_id] = row
-        self.tok_len[req_id] = S
-        self.segments[req_id] = list(segments)
-        self.next_idx[req_id] = 0
-        self.admits += 1
-        return row
+        with self.tracer.span("prefill.admit", req=req_id):
+            if req_id in self.rows:
+                raise ValueError(f"{req_id} already admitted")
+            S = int(h.shape[1])
+            self._ensure_capacity(len(self.rows) + 1, S, h)
+            row = self._free.pop(0)
+            if row in self._ever_used:
+                self.rows_reused += 1
+            self._ever_used.add(row)
+            self.hidden = self.hidden.at[row].set(0).at[row, :S].set(h[0])
+            self._tok_len = self._tok_len.at[row].set(S)
+            for l, s in enumerate(self.rec):
+                if s is not None:
+                    self.rec[l] = jax.tree.map(lambda x: x.at[row].set(0), s)
+            if enc_kvs is not None:
+                if self.enc is None:
+                    self.enc = [tuple(jnp.zeros((self.b_cap,) + a.shape[1:],
+                                                a.dtype) for a in kv)
+                                for kv in enc_kvs]
+                self.enc = [tuple(dst.at[row].set(src[0])
+                                  for dst, src in zip(self.enc[l], enc_kvs[l]))
+                            for l in range(len(self.enc))]
+            self.rows[req_id] = row
+            self.tok_len[req_id] = S
+            self.segments[req_id] = list(segments)
+            self.next_idx[req_id] = 0
+            self.admits += 1
+            return row
 
     def release(self, req_id: str) -> int:
         """Free a finished request's row for reuse."""
@@ -474,69 +475,68 @@ class PrefillPlane:
 
     def _run_group(self, params: Dict, layer: int, start: int,
                    rids: List[str]) -> PrefillGroupRun:
-        cfg = self.cfg
-        kind = M.layer_kind(cfg, layer)
-        tr = self.tracer
-        if tr.enabled:
-            _ts = time.perf_counter()
-        segs = {rid: self.segments[rid][self.next_idx[rid]] for rid in rids}
-        t_cap = min(self.policy.bucket_tokens(
-            max(s.chunk_len for s in segs.values())), self.s_cap - start)
-        smask = np.zeros((self.b_cap,), bool)
-        tmask = np.zeros((self.b_cap, t_cap), bool)
-        for rid in rids:
-            row = self.rows[rid]
-            smask[row] = True
-            tmask[row, :segs[rid].chunk_len] = True
-        smask_j = jnp.asarray(smask)
-        tmask_j = jnp.asarray(tmask)
-        h_win = self.hidden[:, start:start + t_cap]
-        p_l = self._layer_params(params)[layer]
-        if kind == "attn":
-            pos_win = jnp.broadcast_to(
-                jnp.arange(start, start + t_cap, dtype=jnp.int32),
-                (self.b_cap, t_cap))
-            ctx = None
-            if start > 0:
+        with self.tracer.span("prefill.group", layer=layer,
+                              chunk_start=start, rows=len(rids)):
+            cfg = self.cfg
+            kind = M.layer_kind(cfg, layer)
+            segs = {rid: self.segments[rid][self.next_idx[rid]]
+                    for rid in rids}
+            t_cap = min(self.policy.bucket_tokens(
+                max(s.chunk_len for s in segs.values())), self.s_cap - start)
+            smask = np.zeros((self.b_cap,), bool)
+            tmask = np.zeros((self.b_cap, t_cap), bool)
+            for rid in rids:
+                row = self.rows[rid]
+                smask[row] = True
+                tmask[row, :segs[rid].chunk_len] = True
+            smask_j = jnp.asarray(smask)
+            tmask_j = jnp.asarray(tmask)
+            h_win = self.hidden[:, start:start + t_cap]
+            p_l = self._layer_params(params)[layer]
+            if kind == "attn":
+                pos_win = jnp.broadcast_to(
+                    jnp.arange(start, start + t_cap, dtype=jnp.int32),
+                    (self.b_cap, t_cap))
+                ctx = None
+                if start > 0:
+                    if cfg.attention_type == "mla":
+                        raise NotImplementedError(
+                            "chunked layer segments are not supported for MLA "
+                            "models (no latent-context attention path); plan "
+                            "whole-layer segments")
+                    ctx = (self.ctx_k[:, :start], self.ctx_v[:, :start])
+                enc = self.enc[layer] if self.enc is not None else None
+                h_out, kv_out = self.fns.attn(
+                    p_l, h_win, pos_win, tmask_j, smask_j, ctx, enc,
+                    jnp.asarray(start, jnp.int32))
+                rows_arr = jnp.asarray([self.rows[r] for r in rids], jnp.int32)
                 if cfg.attention_type == "mla":
-                    raise NotImplementedError(
-                        "chunked layer segments are not supported for MLA "
-                        "models (no latent-context attention path); plan "
-                        "whole-layer segments")
-                ctx = (self.ctx_k[:, :start], self.ctx_v[:, :start])
-            enc = self.enc[layer] if self.enc is not None else None
-            h_out, kv_out = self.fns.attn(
-                p_l, h_win, pos_win, tmask_j, smask_j, ctx, enc,
-                jnp.asarray(start, jnp.int32))
-            rows_arr = jnp.asarray([self.rows[r] for r in rids], jnp.int32)
-            if cfg.attention_type == "mla":
-                (latent,) = kv_out
-                self._ensure_ctx((latent.shape[2:], None))
-                self.ctx_k = self.ctx_k.at[rows_arr, start:start + t_cap].set(
-                    latent[rows_arr].astype(self.ctx_k.dtype))
+                    (latent,) = kv_out
+                    self._ensure_ctx((latent.shape[2:], None))
+                    self.ctx_k = self.ctx_k.at[
+                        rows_arr, start:start + t_cap].set(
+                        latent[rows_arr].astype(self.ctx_k.dtype))
+                else:
+                    k, v = kv_out
+                    self._ensure_ctx((k.shape[2:], v.shape[2:]))
+                    self.ctx_k = self.ctx_k.at[
+                        rows_arr, start:start + t_cap].set(
+                        k[rows_arr].astype(self.ctx_k.dtype))
+                    self.ctx_v = self.ctx_v.at[
+                        rows_arr, start:start + t_cap].set(
+                        v[rows_arr].astype(self.ctx_v.dtype))
             else:
-                k, v = kv_out
-                self._ensure_ctx((k.shape[2:], v.shape[2:]))
-                self.ctx_k = self.ctx_k.at[rows_arr, start:start + t_cap].set(
-                    k[rows_arr].astype(self.ctx_k.dtype))
-                self.ctx_v = self.ctx_v.at[rows_arr, start:start + t_cap].set(
-                    v[rows_arr].astype(self.ctx_v.dtype))
-        else:
-            h_out, new_state = self.fns.rec[kind](
-                p_l, h_win, tmask_j, smask_j, self.rec[layer])
-            self.rec[layer] = new_state
-        self.hidden = self.hidden.at[:, start:start + t_cap].set(h_out)
-        self.launches += 1
-        if start > 0:
-            self.chunk_launches += 1
-        self.buckets_seen.add((self.b_cap, t_cap))
-        if tr.enabled:
-            tr.end("prefill-group", "prefill", _ts, layer=layer,
-                   chunk_start=start, chunk_cap=t_cap, rows=len(rids),
-                   kind=kind)
-        return PrefillGroupRun(layer=layer, kind=kind, chunk_start=start,
-                               chunk_cap=t_cap, req_ids=list(rids),
-                               segs=segs)
+                h_out, new_state = self.fns.rec[kind](
+                    p_l, h_win, tmask_j, smask_j, self.rec[layer])
+                self.rec[layer] = new_state
+            self.hidden = self.hidden.at[:, start:start + t_cap].set(h_out)
+            self.launches += 1
+            if start > 0:
+                self.chunk_launches += 1
+            self.buckets_seen.add((self.b_cap, t_cap))
+            return PrefillGroupRun(layer=layer, kind=kind, chunk_start=start,
+                                   chunk_cap=t_cap, req_ids=list(rids),
+                                   segs=segs)
 
     def resident_tokens(self) -> Dict[str, int]:
         """Per-row tokens of CURRENT-layer attention KV held right now —
@@ -594,11 +594,12 @@ class PrefillPlane:
         finisher — on the ``HostStageWorker`` — pays the blocking
         ``np.asarray`` plus the per-request trim/transpose and returns
         exactly what ``read_group_kv`` would have."""
-        rows = jnp.asarray([self.rows[r] for r in g.req_ids], jnp.int32)
-        sl = slice(g.chunk_start, g.chunk_start + g.chunk_cap)
-        k_dev = self.ctx_k[rows, sl]
-        v_dev = self.ctx_v[rows, sl] if self.ctx_v is not None else None
-        req_ids = list(g.req_ids)
+        with self.tracer.span("prefill.kv_readback", layer=g.layer):
+            rows = jnp.asarray([self.rows[r] for r in g.req_ids], jnp.int32)
+            sl = slice(g.chunk_start, g.chunk_start + g.chunk_cap)
+            k_dev = self.ctx_k[rows, sl]
+            v_dev = self.ctx_v[rows, sl] if self.ctx_v is not None else None
+            req_ids = list(g.req_ids)
         chunk_lens = {rid: g.segs[rid].chunk_len for rid in req_ids}
 
         def finish() -> Dict[str, Tuple[np.ndarray, Optional[np.ndarray]]]:

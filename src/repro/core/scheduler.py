@@ -17,6 +17,7 @@ from typing import Dict, List, Optional, Tuple
 from repro.core.kv_cache import KVGeometry
 from repro.core.working_set import (DecodeWorkingSet, estimate_decode_ws_bytes,
                                     estimate_prefill_ws_bytes)
+from repro.obs import tracing
 from repro.serving.request import Phase, Request
 
 
@@ -73,6 +74,7 @@ class Scheduler:
         self.top_k_blocks = top_k_blocks
         self.waiting: List[Request] = []
         self.running: List[Request] = []
+        self.tracer = tracing.DEFAULT   # the engine hands its own
         self.working_sets: Dict[str, DecodeWorkingSet] = {}
 
     # ------------------------------------------------------------------
@@ -164,40 +166,41 @@ class Scheduler:
 
     def schedule(self) -> BatchPlan:
         """Algorithm 1: candidate batch -> WS-aware admission."""
-        decode, prefills = self._initial_batch()
-        if not self.cfg.ws_control or self.cfg.m_avl_bytes <= 0:
-            plan = BatchPlan(decode, prefills)
-        else:
-            m_used = 0
-            ws_d = ws_p = 0
-            adm_d: List[Request] = []
-            adm_p: List[Tuple[Request, int]] = []
-            rejected = 0
-            for req in decode:
-                m_req = self._estimate_ws(req)
-                if m_used + m_req <= self.cfg.m_avl_bytes:
-                    adm_d.append(req)
-                    m_used += m_req
-                    ws_d += m_req
-                else:
-                    rejected += 1          # S.reset(req): stays queued
-            for req, inject in prefills:
-                m_req = self._estimate_ws(req)
-                if m_used + m_req <= self.cfg.m_avl_bytes:
-                    adm_p.append((req, inject))
-                    m_used += m_req
-                    ws_p += m_req
-                else:
-                    rejected += 1
-            plan = BatchPlan(adm_d, adm_p, rejected=rejected,
-                             ws_decode_bytes=ws_d, ws_prefill_bytes=ws_p)
+        with self.tracer.span("scheduler.schedule"):
+            decode, prefills = self._initial_batch()
+            if not self.cfg.ws_control or self.cfg.m_avl_bytes <= 0:
+                plan = BatchPlan(decode, prefills)
+            else:
+                m_used = 0
+                ws_d = ws_p = 0
+                adm_d: List[Request] = []
+                adm_p: List[Tuple[Request, int]] = []
+                rejected = 0
+                for req in decode:
+                    m_req = self._estimate_ws(req)
+                    if m_used + m_req <= self.cfg.m_avl_bytes:
+                        adm_d.append(req)
+                        m_used += m_req
+                        ws_d += m_req
+                    else:
+                        rejected += 1          # S.reset(req): stays queued
+                for req, inject in prefills:
+                    m_req = self._estimate_ws(req)
+                    if m_used + m_req <= self.cfg.m_avl_bytes:
+                        adm_p.append((req, inject))
+                        m_used += m_req
+                        ws_p += m_req
+                    else:
+                        rejected += 1
+                plan = BatchPlan(adm_d, adm_p, rejected=rejected,
+                                 ws_decode_bytes=ws_d, ws_prefill_bytes=ws_p)
 
-        # promote admitted waiting requests to running/prefill
-        for req, _ in plan.prefill_reqs:
-            if req.phase == Phase.WAITING:
-                req.phase = Phase.PREFILL
-                self.waiting.remove(req)
-                self.running.append(req)
-        plan.total_tokens = (len(plan.decode_reqs)
-                             + sum(t for _, t in plan.prefill_reqs))
-        return plan
+            # promote admitted waiting requests to running/prefill
+            for req, _ in plan.prefill_reqs:
+                if req.phase == Phase.WAITING:
+                    req.phase = Phase.PREFILL
+                    self.waiting.remove(req)
+                    self.running.append(req)
+            plan.total_tokens = (len(plan.decode_reqs)
+                                 + sum(t for _, t in plan.prefill_reqs))
+            return plan

@@ -2,13 +2,15 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke \
         --requests 8 --prompt 192 --gen 8 [--prefill chunked] [--no-ws] \
-        [--obs] [--trace-out run.trace.json] [--prom]
+        [--trace-out DIR] [--prom]
 
 Prints TTFT/TBT/throughput and the hierarchical-KV transfer statistics
 (FlashH2D/D2H calls, hit rates) — the numbers the paper's Figs. 10–16
 track — all read from ``engine.metrics_snapshot()``, the one obs
-surface.  ``--trace-out`` writes the run's Chrome trace-event JSON
-(open in https://ui.perfetto.dev); ``--prom`` dumps the Prometheus text
+surface.  ``--trace-out DIR`` serves under a ``jax.profiler`` capture
+written to DIR: the program's ``repro:`` spans beside the runtime's host
+events and the device's operations, with a ``perfetto_trace.json.gz``
+that https://ui.perfetto.dev loads; ``--prom`` dumps the Prometheus text
 exposition.
 """
 from __future__ import annotations
@@ -41,25 +43,22 @@ def main(argv=None) -> int:
     ap.add_argument("--no-ws", action="store_true")
     ap.add_argument("--cache-blocks", type=int, default=64)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--obs", action="store_true",
-                    help="enable the tracing+metrics layer (EngineConfig"
-                         ".obs; also via REPRO_OBS=1)")
     ap.add_argument("--trace-out", default="",
-                    help="write Chrome trace-event JSON here (implies "
-                         "--obs; open in ui.perfetto.dev)")
+                    help="serve under a jax.profiler capture written to "
+                         "this directory (open its perfetto_trace.json.gz "
+                         "in ui.perfetto.dev)")
     ap.add_argument("--prom", action="store_true",
                     help="print the Prometheus text exposition of the "
                          "final metrics snapshot")
     args = ap.parse_args(argv)
     enable_compile_cache()
 
-    obs = args.obs or bool(args.trace_out) or None   # None -> REPRO_OBS env
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed), jnp.float32)
     eng = ServingEngine(params, cfg, EngineConfig(
         prefill_mode=args.prefill, chunk_size=args.chunk,
         ws_control=not args.no_ws,
-        hbm_blocks_per_request=args.cache_blocks, seed=args.seed, obs=obs))
+        hbm_blocks_per_request=args.cache_blocks, seed=args.seed))
 
     rng = np.random.default_rng(args.seed)
     t = 0.0
@@ -75,10 +74,14 @@ def main(argv=None) -> int:
                 (1, cfg.num_patches, cfg.d_model), np.float32) * .01
         eng.submit(req, **extra)
 
-    m = eng.run()
+    if args.trace_out:
+        with jax.profiler.trace(args.trace_out, create_perfetto_trace=True):
+            m = eng.run()
+        print(f"trace -> {args.trace_out}")
+    else:
+        m = eng.run()
     s = eng.metrics_snapshot()
-    print(f"arch={cfg.name} prefill={args.prefill} ws={not args.no_ws} "
-          f"obs={int(s['obs.enabled'])}")
+    print(f"arch={cfg.name} prefill={args.prefill} ws={not args.no_ws}")
     print(f"finished={m.num_finished}/{args.requests} "
           f"iters={s['engine.iterations']:.0f}")
     clock = ("measured" if eng.eng.charge_real_time
@@ -98,9 +101,6 @@ def main(argv=None) -> int:
     if overlap is not None:
         print(f"async host-stage overlap: {100*overlap:.1f}% of host-stage "
               f"work off-thread ({s['worker.jobs_run']:.0f} worker jobs)")
-    if args.trace_out:
-        n = eng.dump_trace(args.trace_out)
-        print(f"trace: {n} events -> {args.trace_out}")
     if args.prom:
         print(eng.metrics_prometheus(), end="")
     return 0

@@ -1,28 +1,15 @@
-"""Observability: Chrome-trace spans + one metrics registry.
+"""Observability: profiler spans + one metrics registry.
 
-The engine's async pipeline (dispatch thread + ``HostStageWorker``) and
-mixed hybrid iterations are concurrent by construction; this package is
-how that concurrency becomes *visible*.  Two surfaces:
-
-- :class:`~repro.obs.tracing.Tracer` — thread-safe Chrome trace-event
-  JSON (Perfetto-loadable), one lane per thread.  Disabled by default;
-  ``NULL_TRACER`` is the shared no-op so hot paths stay allocation-free.
+- :class:`~repro.obs.tracing.Tracer` — ``repro:`` spans written as
+  ``jax.profiler.TraceAnnotation``s, so a profiler capture holds them
+  on the same clock as the runtime's host events and the device's
+  operations; the spans ``tracing.COUNTED`` names also feed registry
+  counters.
 - :class:`~repro.obs.metrics.MetricsRegistry` — counters / gauges /
   histograms behind ``engine.metrics_snapshot()`` and a Prometheus text
   exporter.
-
-Span-interval analysis (``achieved_overlap_fraction``) lives in
-:mod:`repro.obs.trace_analysis` and cross-checks the counter-based
-overlap measurement in ``benchmarks/bench_overlap.py``.
 """
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace_analysis import achieved_overlap_fraction
-from repro.obs.tracing import NULL_TRACER, NullTracer, Tracer
+from repro.obs.tracing import Tracer
 
-__all__ = [
-    "MetricsRegistry",
-    "NULL_TRACER",
-    "NullTracer",
-    "Tracer",
-    "achieved_overlap_fraction",
-]
+__all__ = ["MetricsRegistry", "Tracer"]

@@ -5,7 +5,8 @@ Naming scheme (dotted, lowercase): ``engine.*`` iteration-level facts,
 ``sched.*`` scheduler decisions (queue depth, batch sizes, working-set
 estimates), ``kv.*`` FlashH2D/FlashD2H transfer totals and HBM
 residency, ``plane.*`` per-plane staged-decode counters aggregated,
-``worker.*`` the HostStageWorker, ``obs.*`` the obs layer itself.
+``worker.*`` the HostStageWorker; a counted span adds
+``<span>_s``/``<span>_calls``/``<span>_self_s`` (``tracing.COUNTED``).
 
 Instruments are memoized by name — ``registry.gauge("x")`` always
 returns the same object, so hot paths resolve instruments once in

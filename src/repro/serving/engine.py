@@ -59,7 +59,6 @@ benchmarks drive.
 from __future__ import annotations
 
 import dataclasses
-import os
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -81,7 +80,7 @@ from repro.launch.plane_mesh import PlaneMesh
 from repro.models import model as M
 from repro.models.common import ModelConfig
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.tracing import NULL_TRACER, Tracer
+from repro.obs.tracing import Tracer
 from repro.serving import costmodel as cm
 from repro.serving.metrics import ServingMetrics, compute_metrics
 from repro.serving.request import Phase, Request
@@ -210,17 +209,6 @@ class EngineConfig:
     # TransferStats, obs spans, and the cost model's per-layer transfer
     # charges — shrink ~dtype_bytes x while decode output stays within the
     # bench_accuracy cosine bound).  See docs/architecture.md §12.
-    obs: Optional[bool] = None
-    # True: the obs layer is live — the engine builds a Tracer (Chrome
-    # trace-event JSON, one lane per thread; see src/repro/obs/) and
-    # installs it on the planes, the KV manager and the HostStageWorker,
-    # and per-iteration scheduler gauges flow into the MetricsRegistry.
-    # None resolves from the environment (REPRO_OBS=1 enables) into a
-    # COPY, same as the knobs above.  Default off: hot paths pay one
-    # `tracer.enabled` attribute read per instrumentation point and emit
-    # nothing (NULL_TRACER), keeping greedy tokens byte-identical.
-    # `engine.metrics_snapshot()` works either way.
-    # See docs/architecture.md §11.
 
 
 @dataclasses.dataclass
@@ -332,14 +320,10 @@ class ServingEngine:
             eng = dataclasses.replace(
                 eng, charge_real_time=jax.default_backend() != "cpu")
             self.eng = eng
-        if eng.obs is None:
-            # env opt-in so benches/CI can trace without touching configs;
-            # resolve into a COPY (same rationale as the knobs above)
-            eng = dataclasses.replace(
-                eng, obs=os.environ.get("REPRO_OBS", "") == "1")
-            self.eng = eng
-        self.tracer = Tracer() if eng.obs else NULL_TRACER
+        # spans on the profiler's clock, always on (src/repro/obs/); the
+        # counted ones feed this registry
         self.metrics = MetricsRegistry()
+        self.tracer = Tracer(self.metrics)
         self.mc = cm.ModelCost.from_config(cfg)
         self.rng = np.random.default_rng(eng.seed)
 
@@ -365,6 +349,7 @@ class ServingEngine:
         self.kv_mgr = KVCacheManager(self.geom, eng.hbm_budget_bytes,
                                      offload_quant=eng.offload_quant)
         self.kv_mgr.tracer = self.tracer
+        self.scheduler.tracer = self.tracer
         # wire bytes of one (layer, block) transfer at the offload tier's
         # STORED size — what the cost model charges per moved block (int8
         # payload + scales under offload_quant="int8"; the modeled bf16
@@ -419,7 +404,8 @@ class ServingEngine:
         self._g_hbm_used = _m.gauge(
             "kv.hbm_used_bytes", "actual HBM residency after the iteration")
         self._h_iter = _m.histogram(
-            "engine.iteration_s", "wall-clock seconds per engine iteration")
+            "engine.iteration_s", "seconds per engine iteration (the "
+            "engine.step span)")
         self.mixed_iter_log: List[Dict[str, Any]] = []
         # per mixed iteration: per-layer fused d2h/h2d call counts, group
         # counts and the measured jitted-launch total — what
@@ -909,23 +895,25 @@ class ServingEngine:
         spent: Dict[str, int] = {}
 
         # prefill jobs (admission mirrors _prefill_plane_iteration)
-        pre_h = self._batched_admit_embed(
-            [self.states[req.req_id] for req, _ in plan.prefill_reqs
-             if req.req_id not in self._req_prefill_plane])
-        by_plane: Dict[int, Tuple[PrefillPlane, Dict[str, int]]] = {}
-        for req, inject in plan.prefill_reqs:
-            st = self.states[req.req_id]
-            if req.scheduled_time is None:
-                req.scheduled_time = self.now
-            plane = self._req_prefill_plane.get(req.req_id)
-            if plane is None:
-                plane = self._admit_prefill_plane(st,
-                                                  h=pre_h.get(req.req_id))
-            st.prefill_carry += max(int(inject), 1)
-            _, allow = by_plane.setdefault(id(plane), (plane, {}))
-            allow[req.req_id] = st.prefill_carry
-        prefill_jobs = [PrefillJob(plane, allow)
-                        for plane, allow in by_plane.values()]
+        tr = self.tracer
+        with tr.span("engine.prefill"):
+            pre_h = self._batched_admit_embed(
+                [self.states[req.req_id] for req, _ in plan.prefill_reqs
+                 if req.req_id not in self._req_prefill_plane])
+            by_plane: Dict[int, Tuple[PrefillPlane, Dict[str, int]]] = {}
+            for req, inject in plan.prefill_reqs:
+                st = self.states[req.req_id]
+                if req.scheduled_time is None:
+                    req.scheduled_time = self.now
+                plane = self._req_prefill_plane.get(req.req_id)
+                if plane is None:
+                    plane = self._admit_prefill_plane(st,
+                                                      h=pre_h.get(req.req_id))
+                st.prefill_carry += max(int(inject), 1)
+                _, allow = by_plane.setdefault(id(plane), (plane, {}))
+                allow[req.req_id] = st.prefill_carry
+            prefill_jobs = [PrefillJob(plane, allow)
+                            for plane, allow in by_plane.values()]
 
         # decode jobs (grouping mirrors step()'s split decode dispatch)
         groups: Dict[Tuple, List[_ReqState]] = {}
@@ -1196,13 +1184,17 @@ class ServingEngine:
         for pj in prefill_jobs:
             involved[id(pj.plane.fns)] = pj.plane.fns
         calls0 = sum(f.calls for f in involved.values())
-        res = self.hybrid.run_iteration(
-            self.params, decode_jobs, prefill_jobs,
-            layer_cb_async if worker is not None else layer_cb_sync)
+        # the one layer walk: decode groups, and any prefill groups riding
+        # the same layers
+        with tr.span("engine.decode", prefill_jobs=len(prefill_jobs)):
+            res = self.hybrid.run_iteration(
+                self.params, decode_jobs, prefill_jobs,
+                layer_cb_async if worker is not None else layer_cb_sync)
         if worker is not None:
             # iteration fence: every merged write-back has landed before
             # the epilogues sample logits or release DRAM pools
-            worker.drain()
+            with tr.span("worker.drain"):
+                worker.drain()
         entry["launches"] = sum(f.calls
                                 for f in involved.values()) - calls0
 
@@ -1212,8 +1204,9 @@ class ServingEngine:
             self.decode_step_calls += 1
             self.decode_tokens += len(sts)
             if drop:
-                self._drop_pending_evictions(plane, sts,
-                                             pending_evict[id(plane)])
+                with tr.span("engine.drop_pending"):
+                    self._drop_pending_evictions(plane, sts,
+                                                 pending_evict[id(plane)])
             for st in sts:
                 row = plane.rows[st.req.req_id]
                 st.last_logits = logits[row:row + 1]
@@ -1223,47 +1216,48 @@ class ServingEngine:
                         st.req, sel_pairs[st.req.req_id])
 
         # prefill epilogue (mirrors _prefill_plane_iteration's tail)
-        for plane, pres in res.prefill:
-            entry["groups"] += len(pres.groups)
-            entry["finalize"] += 1 if pres.finished else 0
-            _, allow = by_plane[id(plane)]
-            for rid in allow:
-                st_r = self.states[rid]
-                st_r.prefill_carry = max(
-                    0, st_r.prefill_carry - spent.get(rid, 0))
-                req = st_r.req
-                if not plane.done(rid):
-                    seg = plane.segments[rid][plane.next_idx[rid]]
-                    req.prefill_layer = seg.layer
-                    req.prefill_layer_tokens_done = min(
-                        seg.chunk_start, max(req.prompt_len - 1, 0))
-            for rid, peak in pres.peaks.items():
-                fp += hbm_footprint_tokens(
-                    plane.tok_len[rid], "layer_segmented", L,
-                    layer_tokens_resident=peak)
-            for rid in pres.finished:
-                st_r = self.states[rid]
-                row = plane.rows[rid]
-                st_r.last_logits = pres.logits[row:row + 1]
-                caches = st_r.decode_state["caches"]
-                for l in range(L):
-                    if caches[l] is None and M.layer_kind(self.cfg,
-                                                          l) != "attn":
-                        caches[l] = plane.rec_state(rid, l)
-                st_r.decode_state["cur_len"] = jnp.full(
-                    (1,), plane.tok_len[rid], jnp.int32)
-                st_r.req.prefill_layer = L
-                st_r.req.prefill_layer_tokens_done = 0
-                plane.release(rid)
-                self._req_prefill_plane.pop(rid, None)
-                done.append(st_r.req)
-        for plane in self.prefill_planes.values():
-            if id(plane) in by_plane:
-                continue
-            for rid, resident in plane.resident_tokens().items():
-                fp += hbm_footprint_tokens(
-                    plane.tok_len[rid], "layer_segmented", L,
-                    layer_tokens_resident=resident)
+        with tr.span("engine.prefill"):
+            for plane, pres in res.prefill:
+                entry["groups"] += len(pres.groups)
+                entry["finalize"] += 1 if pres.finished else 0
+                _, allow = by_plane[id(plane)]
+                for rid in allow:
+                    st_r = self.states[rid]
+                    st_r.prefill_carry = max(
+                        0, st_r.prefill_carry - spent.get(rid, 0))
+                    req = st_r.req
+                    if not plane.done(rid):
+                        seg = plane.segments[rid][plane.next_idx[rid]]
+                        req.prefill_layer = seg.layer
+                        req.prefill_layer_tokens_done = min(
+                            seg.chunk_start, max(req.prompt_len - 1, 0))
+                for rid, peak in pres.peaks.items():
+                    fp += hbm_footprint_tokens(
+                        plane.tok_len[rid], "layer_segmented", L,
+                        layer_tokens_resident=peak)
+                for rid in pres.finished:
+                    st_r = self.states[rid]
+                    row = plane.rows[rid]
+                    st_r.last_logits = pres.logits[row:row + 1]
+                    caches = st_r.decode_state["caches"]
+                    for l in range(L):
+                        if caches[l] is None and M.layer_kind(self.cfg,
+                                                              l) != "attn":
+                            caches[l] = plane.rec_state(rid, l)
+                    st_r.decode_state["cur_len"] = jnp.full(
+                        (1,), plane.tok_len[rid], jnp.int32)
+                    st_r.req.prefill_layer = L
+                    st_r.req.prefill_layer_tokens_done = 0
+                    plane.release(rid)
+                    self._req_prefill_plane.pop(rid, None)
+                    done.append(st_r.req)
+            for plane in self.prefill_planes.values():
+                if id(plane) in by_plane:
+                    continue
+                for rid, resident in plane.resident_tokens().items():
+                    fp += hbm_footprint_tokens(
+                        plane.tok_len[rid], "layer_segmented", L,
+                        layer_tokens_resident=resident)
         self.mixed_iter_log.append(entry)
         return loads_total[0], done, fp, prefill_by_layer
 
@@ -1271,12 +1265,13 @@ class ServingEngine:
     # Decode execution
     # ------------------------------------------------------------------
     def _sample(self, st: _ReqState) -> int:
-        logits = np.asarray(st.last_logits, np.float32)[0]
-        if self.eng.greedy:
-            return int(np.argmax(logits))
-        z = logits - logits.max()
-        p = np.exp(z) / np.exp(z).sum()
-        return int(self.rng.choice(len(p), p=p))
+        with self.tracer.span("sampling", req=st.req.req_id):
+            logits = np.asarray(st.last_logits, np.float32)[0]
+            if self.eng.greedy:
+                return int(np.argmax(logits))
+            z = logits - logits.max()
+            p = np.exp(z) / np.exp(z).sum()
+            return int(self.rng.choice(len(p), p=p))
 
     def _account_selections(self, sts: List[_ReqState],
                             selected: Dict[int, Any],
@@ -1709,19 +1704,23 @@ class ServingEngine:
                 worker.fence(lidx)   # probes compare device vs host pools
                 self.staged_probe(self, plane, layer, sts, blocks_by_req)
 
-        logits, info, prev = plane.step_staged(
-            self.params, tok_by_req,
-            stage_cb_async if worker is not None else stage_cb_sync)
+        tr = self.tracer
+        with tr.span("engine.decode"):
+            logits, info, prev = plane.step_staged(
+                self.params, tok_by_req,
+                stage_cb_async if worker is not None else stage_cb_sync)
         if worker is not None:
             # iteration fence: every write-back has landed before sampling
             # reads logits and before finish/release can retire a DRAM pool
-            worker.drain()
+            with tr.span("worker.drain"):
+                worker.drain()
         self.decode_step_calls += 1
         self.decode_tokens += len(sts)
         if drop:
             # evictions deferred past their own attend stage: safe to zero
             # now that every layer's compute has run
-            self._drop_pending_evictions(plane, sts, pending_evict)
+            with tr.span("engine.drop_pending"):
+                self._drop_pending_evictions(plane, sts, pending_evict)
         for st in sts:
             row = plane.rows[st.req.req_id]
             st.last_logits = logits[row:row + 1]
@@ -1753,225 +1752,246 @@ class ServingEngine:
         the device finishing the iteration (``charge_real_time``, the
         accelerator default) or the analytic cost model; transfer stats
         are in bytes/calls/blocks with each moved block counted exactly
-        once (see ``KVCacheManager``)."""
-        self._admit_arrivals()
-        plan = self.scheduler.schedule()
-        if not plan.decode_reqs and not plan.prefill_reqs:
-            if self._pending:      # idle until the next arrival
+        once (see ``KVCacheManager``).  The iteration is the span
+        ``engine.step``, its phases the spans below it
+        (docs/architecture.md §11)."""
+        tr = self.tracer
+        with tr.span("engine.step") as sp:
+            self._admit_arrivals()
+            plan = self.scheduler.schedule()
+            while not plan.decode_reqs and not plan.prefill_reqs:
+                if not self._pending:
+                    return None
+                # idle until the next arrival
                 self.now = max(self.now, self._pending[0].arrival_time)
-                return self.step()
-            return None
-        t0 = time.perf_counter()
-        iter_loads = 0
-        self._staged_layer_bytes = {}
-        mixed = self.hybrid is not None
+                self._admit_arrivals()
+                plan = self.scheduler.schedule()
+            sp.set(iteration=self.iterations,
+                   decode_rows=len(plan.decode_reqs),
+                   prefill_rows=len(plan.prefill_reqs))
+            t0 = time.perf_counter()
+            iter_loads = 0
+            self._staged_layer_bytes = {}
+            mixed = self.hybrid is not None
 
-        # --- prefill segments ------------------------------------------
-        t_prefill = 0.0
-        prefill_by_layer: Optional[List[float]] = None
-        prefill_done: List[Request] = []
-        iter_prefill_fp = 0          # HBM watermark, token-layer units,
-                                     # summed over the iteration's batch
-        scheduled_prefill = {req.req_id for req, _ in plan.prefill_reqs}
-        if mixed:
-            # ONE mixed iteration carries BOTH phases: decode groups and
-            # prefill planes share one layer walk and one per-layer host
-            # stage (hybrid_plane.HybridPlane); decode sampling and the
-            # prefill epilogue already ran inside
-            iter_loads, prefill_done, iter_prefill_fp, prefill_by_layer = \
-                self._mixed_iteration(plan)
-        elif (self.eng.prefill_mode == "layer_segmented"
-                and self.eng.prefill_exec == "plane"):
-            # with no scheduled prefill this still books the watermark of
-            # rows parked mid-layer in the planes
-            t_prefill, prefill_done, iter_prefill_fp = \
-                self._prefill_plane_iteration(plan.prefill_reqs)
-        else:
-            for req, inject in plan.prefill_reqs:
-                st = self.states[req.req_id]
-                if req.scheduled_time is None:
-                    req.scheduled_time = self.now
-                if self.eng.prefill_mode == "layer_segmented":
-                    if st.lp is None:
-                        # whole-layer segments; inject (token-layers)
-                        # decides how many run per iteration
-                        self._start_layer_segmented(st, req.prompt_len)
-                    # advance the scheduler cursor by `inject` token-layers
-                    # (cursor = source of truth; >=1 whole layer/iteration)
-                    req.prefill_layer_tokens_done += max(inject,
-                                                         req.prompt_len)
-                    while (req.prefill_layer_tokens_done >= req.prompt_len
-                           and req.prefill_layer < self.cfg.num_layers):
-                        req.prefill_layer += 1
-                        req.prefill_layer_tokens_done -= req.prompt_len
-                    # run segments to catch the cursor up
-                    done = False
-                    ran = False
-                    while (st.lp is not None and not done
-                           and st.lp.next_idx < req.prefill_layer):
-                        done = self._run_layer_segment(st)
-                        ran = True
-                        t_prefill += cm.batched_prefill_time(
-                            self.hw, self.mc,
-                            [(req.prompt_len, req.prompt_len)], layers=1)
-                    if ran:
-                        # the whole layer's KV is live while segments run
-                        iter_prefill_fp += hbm_footprint_tokens(
-                            req.prompt_len, "layer_segmented",
-                            self.cfg.num_layers)
-                else:
-                    done = self._run_chunked_prefill(st, inject)
-                    ctx = req.prefill_tokens_done
-                    t_prefill += cm.prefill_time(self.hw, self.mc, inject,
-                                                 ctx)
-                    iter_prefill_fp += hbm_footprint_tokens(
-                        req.prompt_len, "chunked", self.cfg.num_layers,
-                        req.prefill_tokens_done)
-                if done:
-                    prefill_done.append(req)
-        # chunked prefill keeps every processed token's KV (all layers)
-        # resident BETWEEN iterations too — count unscheduled holders
-        for st in self.states.values():
-            if (st.chunk_ctx is not None
-                    and st.req.req_id not in scheduled_prefill):
-                iter_prefill_fp += hbm_footprint_tokens(
-                    st.req.prompt_len, "chunked", self.cfg.num_layers,
-                    st.req.prefill_tokens_done)
-        self.prefill_hbm_peak_tokens = max(self.prefill_hbm_peak_tokens,
-                                           iter_prefill_fp)
-        for req in prefill_done:
-            st = self.states[req.req_id]
-            req.phase = Phase.DECODE
-            req.prefill_tokens_done = req.prompt_len
-            st.out_tokens.append(self._sample(st))       # the first token
-            req.generated = 1
-            req.first_token_time = self.now   # charged below
-            req.token_times.append(self.now)
-
-        # --- decode steps ----------------------------------------------
-        if mixed:
-            pass       # decode rode the mixed iteration above
-        elif self.eng.batched_decode:
-            # ONE scheduler-planned batched forward over all running decode
-            # requests (grouped only when per-request extra shapes differ,
-            # e.g. whisper encoder lengths)
-            groups: Dict[Tuple, List[_ReqState]] = {}
-            for req in plan.decode_reqs:
-                st = self.states[req.req_id]
-                if st.group_key is None:
-                    st.group_key = self._decode_group_key(st)
-                groups.setdefault(st.group_key, []).append(st)
-            for key, sts in groups.items():
-                if self.eng.decode_plane == "staged":
-                    iter_loads += self._decode_batch_staged(key, sts)
-                elif self.eng.decode_plane == "persistent":
-                    iter_loads += self._decode_batch_persistent(key, sts)
-                else:
-                    iter_loads += self._decode_batch(sts)
-        else:
-            for req in plan.decode_reqs:
-                st = self.states[req.req_id]
-                _, loads = self._decode_one(st)
-                iter_loads += loads
-        for req in plan.decode_reqs:
-            req.generated += 1
-            req.token_times.append(self.now)
-            if req.generated >= req.max_new_tokens:
-                req.finish_time = self.now
-                self.scheduler.finish_request(req)
-                self.kv_mgr.release(req.req_id)
-                plane = self._req_plane.pop(req.req_id, None)
-                if plane is not None:
-                    plane.release(req.req_id)   # device slots reusable
-
-        # --- charge time -------------------------------------------------
-        if self.eng.charge_real_time:
-            self._wait_device()
-            t_iter = time.perf_counter() - t0
-        else:
-            attended = min(self.cfg.dsa.token_budget, 1 << 30) \
-                if self.cfg.dsa.enabled else 4096
+            # --- prefill segments ------------------------------------------
+            t_prefill = 0.0
+            prefill_by_layer: Optional[List[float]] = None
+            prefill_done: List[Request] = []
+            iter_prefill_fp = 0          # HBM watermark, token-layer units,
+                                         # summed over the iteration's batch
+            scheduled_prefill = {req.req_id for req, _ in plan.prefill_reqs}
             if mixed:
-                # one shared walk: per layer, the union of decode+prefill
-                # compute overlaps the ONE fused transfer stage
-                n_shards = (self.plane_mesh.model_size
-                            if self.plane_mesh is not None else 1)
-                ag_bytes = None
-                if n_shards > 1 and plan.decode_reqs:
-                    sel_bytes = (len(plan.decode_reqs)
-                                 * self.geom.num_kv_heads
-                                 * self.cfg.dsa.top_k_blocks * 4)
-                    ag_bytes = [
-                        sel_bytes if M.layer_kind(self.cfg, l) == "attn"
-                        else 0 for l in range(self.cfg.num_layers)]
-                t_iter = cm.mixed_iteration_time(
-                    self.hw, self.mc, len(plan.decode_reqs), attended,
-                    [self._staged_layer_bytes.get(l, 0)
-                     for l in range(self.cfg.num_layers)],
-                    prefill_time_by_layer=prefill_by_layer,
-                    n_shards=n_shards, allgather_bytes_by_layer=ag_bytes)
-            elif (plan.decode_reqs and self.eng.batched_decode
-                    and self.eng.decode_plane == "staged"):
-                # staged pipeline: per layer, H2D restores overlap compute
-                # -> charge max(compute, transfer) per layer, not the sum.
-                # Sharded plane: each shard restores only its own slots
-                # (per-shard transfer), plus one all-gather of the selected
-                # block ids per attention layer (the host needs GLOBAL ids
-                # for the LRU and the FlashH2D staging).
-                n_shards = (self.plane_mesh.model_size
-                            if self.plane_mesh is not None else 1)
-                ag_bytes = None
-                if n_shards > 1:
-                    sel_bytes = (len(plan.decode_reqs)
-                                 * self.geom.num_kv_heads
-                                 * self.cfg.dsa.top_k_blocks * 4)
-                    ag_bytes = [
-                        sel_bytes if M.layer_kind(self.cfg, l) == "attn"
-                        else 0 for l in range(self.cfg.num_layers)]
-                t_dec = cm.overlapped_decode_time(
-                    self.hw, self.mc, max(len(plan.decode_reqs), 1),
-                    attended,
-                    [self._staged_layer_bytes.get(l, 0)
-                     for l in range(self.cfg.num_layers)],
-                    n_shards=n_shards, allgather_bytes_by_layer=ag_bytes)
-                t_iter = t_dec + t_prefill
+                # ONE mixed iteration carries BOTH phases: decode groups and
+                # prefill planes share one layer walk and one per-layer host
+                # stage (hybrid_plane.HybridPlane); decode sampling and the
+                # prefill epilogue already ran inside
+                iter_loads, prefill_done, iter_prefill_fp, prefill_by_layer = \
+                    self._mixed_iteration(plan)
+            elif (self.eng.prefill_mode == "layer_segmented"
+                    and self.eng.prefill_exec == "plane"):
+                # with no scheduled prefill this still books the watermark
+                # of rows parked mid-layer in the planes
+                with tr.span("engine.prefill"):
+                    t_prefill, prefill_done, iter_prefill_fp = \
+                        self._prefill_plane_iteration(plan.prefill_reqs)
             else:
-                t_dec = cm.decode_time(
-                    self.hw, self.mc, max(len(plan.decode_reqs), 1),
-                    attended) if plan.decode_reqs else 0.0
-                t_load = cm.fused_transfer_time(
-                    self.hw,
-                    iter_loads * self._offload_block_bytes) \
-                    if iter_loads else 0.0
-                t_iter = t_dec + t_load + t_prefill
-        self.now += max(t_iter, 1e-9)
-        # stamp the times that were logically produced "at end of iteration"
-        for req in plan.decode_reqs + [r for r, _ in plan.prefill_reqs]:
-            if req.token_times and req.token_times[-1] != self.now:
-                req.token_times[-1] = self.now
-            if req.first_token_time is not None and req.generated == 1:
-                req.first_token_time = self.now
-            if req.finish_time is not None and req.phase == Phase.FINISHED:
-                req.finish_time = self.now
-        self.loads_per_iter.append(iter_loads)
-        self.iterations += 1
-        # obs epilogue: outside the dispatch windows by construction (the
-        # planes and the worker have all returned / been drained)
-        wall_s = time.perf_counter() - t0
-        self._h_iter.observe(wall_s)
-        waiting, running = self.scheduler.queue_depths()
-        self._g_queue.set(waiting)
-        self._g_running.set(running)
-        self._g_batch_decode.set(len(plan.decode_reqs))
-        self._g_batch_prefill.set(len(plan.prefill_reqs))
-        self._g_ws_decode.set(plan.ws_decode_bytes)
-        self._g_ws_prefill.set(plan.ws_prefill_bytes)
-        self._g_hbm_used.set(self.kv_mgr.hbm_used_bytes())
-        if self.tracer.enabled:
-            self.tracer.complete_at(
-                "iteration", "engine", t0, wall_s, i=self.iterations - 1,
-                decode_rows=len(plan.decode_reqs),
-                prefill_rows=len(plan.prefill_reqs))
+                with tr.span("engine.prefill"):
+                    for req, inject in plan.prefill_reqs:
+                        st = self.states[req.req_id]
+                        if req.scheduled_time is None:
+                            req.scheduled_time = self.now
+                        if self.eng.prefill_mode == "layer_segmented":
+                            if st.lp is None:
+                                # whole-layer segments; inject
+                                # (token-layers) decides how many run per
+                                # iteration
+                                self._start_layer_segmented(
+                                    st, req.prompt_len)
+                            # advance the scheduler cursor by `inject`
+                            # token-layers (cursor = source of truth; >=1
+                            # whole layer/iteration)
+                            req.prefill_layer_tokens_done += max(
+                                inject, req.prompt_len)
+                            while (req.prefill_layer_tokens_done
+                                   >= req.prompt_len
+                                   and req.prefill_layer
+                                   < self.cfg.num_layers):
+                                req.prefill_layer += 1
+                                req.prefill_layer_tokens_done -= \
+                                    req.prompt_len
+                            # run segments to catch the cursor up
+                            done = False
+                            ran = False
+                            while (st.lp is not None and not done
+                                   and st.lp.next_idx < req.prefill_layer):
+                                done = self._run_layer_segment(st)
+                                ran = True
+                                t_prefill += cm.batched_prefill_time(
+                                    self.hw, self.mc,
+                                    [(req.prompt_len, req.prompt_len)],
+                                    layers=1)
+                            if ran:
+                                # the whole layer's KV is live while
+                                # segments run
+                                iter_prefill_fp += hbm_footprint_tokens(
+                                    req.prompt_len, "layer_segmented",
+                                    self.cfg.num_layers)
+                        else:
+                            done = self._run_chunked_prefill(st, inject)
+                            ctx = req.prefill_tokens_done
+                            t_prefill += cm.prefill_time(
+                                self.hw, self.mc, inject, ctx)
+                            iter_prefill_fp += hbm_footprint_tokens(
+                                req.prompt_len, "chunked",
+                                self.cfg.num_layers,
+                                req.prefill_tokens_done)
+                        if done:
+                            prefill_done.append(req)
+            # chunked prefill keeps every processed token's KV (all layers)
+            # resident BETWEEN iterations too — count unscheduled holders
+            for st in self.states.values():
+                if (st.chunk_ctx is not None
+                        and st.req.req_id not in scheduled_prefill):
+                    iter_prefill_fp += hbm_footprint_tokens(
+                        st.req.prompt_len, "chunked", self.cfg.num_layers,
+                        st.req.prefill_tokens_done)
+            self.prefill_hbm_peak_tokens = max(self.prefill_hbm_peak_tokens,
+                                               iter_prefill_fp)
+            for req in prefill_done:
+                st = self.states[req.req_id]
+                req.phase = Phase.DECODE
+                req.prefill_tokens_done = req.prompt_len
+                st.out_tokens.append(self._sample(st))       # the first token
+                req.generated = 1
+                req.first_token_time = self.now   # charged below
+                req.token_times.append(self.now)
+
+            # --- decode steps ----------------------------------------------
+            if mixed:
+                pass       # decode rode the mixed iteration above
+            elif self.eng.batched_decode:
+                # ONE scheduler-planned batched forward over all running decode
+                # requests (grouped only when per-request extra shapes differ,
+                # e.g. whisper encoder lengths)
+                groups: Dict[Tuple, List[_ReqState]] = {}
+                for req in plan.decode_reqs:
+                    st = self.states[req.req_id]
+                    if st.group_key is None:
+                        st.group_key = self._decode_group_key(st)
+                    groups.setdefault(st.group_key, []).append(st)
+                for key, sts in groups.items():
+                    if self.eng.decode_plane == "staged":
+                        # opens engine.decode around its layer walk
+                        iter_loads += self._decode_batch_staged(key, sts)
+                        continue
+                    with tr.span("engine.decode"):
+                        if self.eng.decode_plane == "persistent":
+                            iter_loads += self._decode_batch_persistent(
+                                key, sts)
+                        else:
+                            iter_loads += self._decode_batch(sts)
+            else:
+                with tr.span("engine.decode"):
+                    for req in plan.decode_reqs:
+                        st = self.states[req.req_id]
+                        _, loads = self._decode_one(st)
+                        iter_loads += loads
+            with tr.span("engine.finish"):
+                for req in plan.decode_reqs:
+                    req.generated += 1
+                    req.token_times.append(self.now)
+                    if req.generated >= req.max_new_tokens:
+                        req.finish_time = self.now
+                        self.scheduler.finish_request(req)
+                        self.kv_mgr.release(req.req_id)
+                        plane = self._req_plane.pop(req.req_id, None)
+                        if plane is not None:
+                            plane.release(req.req_id)   # device slots reusable
+
+            # --- charge time -------------------------------------------------
+            if self.eng.charge_real_time:
+                self._wait_device()
+                t_iter = time.perf_counter() - t0
+            else:
+                attended = min(self.cfg.dsa.token_budget, 1 << 30) \
+                    if self.cfg.dsa.enabled else 4096
+                if mixed:
+                    # one shared walk: per layer, the union of decode+prefill
+                    # compute overlaps the ONE fused transfer stage
+                    n_shards = (self.plane_mesh.model_size
+                                if self.plane_mesh is not None else 1)
+                    ag_bytes = None
+                    if n_shards > 1 and plan.decode_reqs:
+                        sel_bytes = (len(plan.decode_reqs)
+                                     * self.geom.num_kv_heads
+                                     * self.cfg.dsa.top_k_blocks * 4)
+                        ag_bytes = [
+                            sel_bytes if M.layer_kind(self.cfg, l) == "attn"
+                            else 0 for l in range(self.cfg.num_layers)]
+                    t_iter = cm.mixed_iteration_time(
+                        self.hw, self.mc, len(plan.decode_reqs), attended,
+                        [self._staged_layer_bytes.get(l, 0)
+                         for l in range(self.cfg.num_layers)],
+                        prefill_time_by_layer=prefill_by_layer,
+                        n_shards=n_shards, allgather_bytes_by_layer=ag_bytes)
+                elif (plan.decode_reqs and self.eng.batched_decode
+                        and self.eng.decode_plane == "staged"):
+                    # staged pipeline: per layer, H2D restores overlap compute
+                    # -> charge max(compute, transfer) per layer, not the sum.
+                    # Sharded plane: each shard restores only its own slots
+                    # (per-shard transfer), plus one all-gather of the selected
+                    # block ids per attention layer (the host needs GLOBAL ids
+                    # for the LRU and the FlashH2D staging).
+                    n_shards = (self.plane_mesh.model_size
+                                if self.plane_mesh is not None else 1)
+                    ag_bytes = None
+                    if n_shards > 1:
+                        sel_bytes = (len(plan.decode_reqs)
+                                     * self.geom.num_kv_heads
+                                     * self.cfg.dsa.top_k_blocks * 4)
+                        ag_bytes = [
+                            sel_bytes if M.layer_kind(self.cfg, l) == "attn"
+                            else 0 for l in range(self.cfg.num_layers)]
+                    t_dec = cm.overlapped_decode_time(
+                        self.hw, self.mc, max(len(plan.decode_reqs), 1),
+                        attended,
+                        [self._staged_layer_bytes.get(l, 0)
+                         for l in range(self.cfg.num_layers)],
+                        n_shards=n_shards, allgather_bytes_by_layer=ag_bytes)
+                    t_iter = t_dec + t_prefill
+                else:
+                    t_dec = cm.decode_time(
+                        self.hw, self.mc, max(len(plan.decode_reqs), 1),
+                        attended) if plan.decode_reqs else 0.0
+                    t_load = cm.fused_transfer_time(
+                        self.hw,
+                        iter_loads * self._offload_block_bytes) \
+                        if iter_loads else 0.0
+                    t_iter = t_dec + t_load + t_prefill
+            self.now += max(t_iter, 1e-9)
+            # stamp the times that were logically produced "at end of
+            # iteration"
+            for req in plan.decode_reqs + [r for r, _ in plan.prefill_reqs]:
+                if req.token_times and req.token_times[-1] != self.now:
+                    req.token_times[-1] = self.now
+                if req.first_token_time is not None and req.generated == 1:
+                    req.first_token_time = self.now
+                if req.finish_time is not None and req.phase == Phase.FINISHED:
+                    req.finish_time = self.now
+            self.loads_per_iter.append(iter_loads)
+            self.iterations += 1
+            # gauges: outside the dispatch windows by construction (the planes
+            # and the worker have all returned / been drained)
+            waiting, running = self.scheduler.queue_depths()
+            self._g_queue.set(waiting)
+            self._g_running.set(running)
+            self._g_batch_decode.set(len(plan.decode_reqs))
+            self._g_batch_prefill.set(len(plan.prefill_reqs))
+            self._g_ws_decode.set(plan.ws_decode_bytes)
+            self._g_ws_prefill.set(plan.ws_prefill_bytes)
+            self._g_hbm_used.set(self.kv_mgr.hbm_used_bytes())
+        self._h_iter.observe(sp.dur)
         return plan
 
     def _wait_device(self) -> None:
@@ -1979,9 +1999,10 @@ class ServingEngine:
         dispatched.  Sampling already read the logits of rows that
         produced a token; prefill rows parked mid-walk may still have
         work queued on their plane's residual stream and decode pools."""
-        jax.block_until_ready(
-            [p.state for p in self.planes.values()]
-            + [p.hidden for p in self.prefill_planes.values()])
+        with self.tracer.span("engine.wait_device"):
+            jax.block_until_ready(
+                [p.state for p in self.planes.values()]
+                + [p.hidden for p in self.prefill_planes.values()])
 
     def run(self, max_iters: int = 10_000) -> ServingMetrics:
         """Step until idle (every submitted request finished) or
@@ -2007,10 +2028,11 @@ class ServingEngine:
     def metrics_snapshot(self) -> Dict[str, float]:
         """One flat dict over every subsystem's counters — the single obs
         surface (naming scheme: obs/metrics.py).  Registry instruments
-        (sched.* gauges, engine.iteration_s histogram) merge with derived
-        reads of the hot counters, which stay where the hot paths already
-        increment them.  Works with obs disabled; blocking-free but not
-        for dispatch windows (the analyzer flags it there)."""
+        (sched.* gauges, engine.iteration_s histogram, the counted spans'
+        ``*_s``/``*_calls``/``*_self_s``) merge with derived reads of the
+        hot counters, which stay where the hot paths already increment
+        them.  Blocking-free but not for dispatch windows (the analyzer
+        flags it there)."""
         self._g_hbm_used.set(self.kv_mgr.hbm_used_bytes())
         snap = self.metrics.snapshot()
         ts = self.kv_mgr.total_stats()
@@ -2076,8 +2098,6 @@ class ServingEngine:
                                      + (w.jobs_run if live else 0)),
             "worker.busy_s": (self.worker_busy_s
                               + (w.busy_s if live else 0.0)),
-            "obs.enabled": 1.0 if self.tracer.enabled else 0.0,
-            "obs.trace_events": float(len(self.tracer.events())),
         })
         return snap
 
@@ -2091,10 +2111,9 @@ class ServingEngine:
     def stage_overlap_measured(self) -> Optional[float]:
         """Counter instrument for achieved async overlap: the fraction of
         host-stage work that ran on the HostStageWorker thread,
-        ``busy_s / (busy_s + dispatch host_stage_s)``.  ``None`` when no
-        worker job ran (sync mode, or no staged decode).  Cross-checked
-        against the trace instrument (:meth:`stage_overlap_from_trace`)
-        by bench_overlap and the nightly assert."""
+        ``busy_s / (busy_s + dispatch host_stage_s)``, both read from
+        the ``worker.job`` / ``plane.host_stage`` spans.  ``None`` when no
+        worker job ran (sync mode, or no staged decode)."""
         w = self._worker
         busy = self.worker_busy_s + (w.busy_s if w is not None
                                      and not w.closed else 0.0)
@@ -2104,23 +2123,3 @@ class ServingEngine:
         if self.hybrid is not None:
             stage_s += self.hybrid.host_stage_s
         return busy / (busy + stage_s)
-
-    def stage_overlap_from_trace(self) -> Optional[float]:
-        """Trace instrument: span-interval overlap of worker-thread
-        host-stage spans with dispatch-thread iteration spans (see
-        obs/trace_analysis.py).  ``None`` with obs disabled or no worker
-        spans."""
-        from repro.obs.trace_analysis import achieved_overlap_fraction
-        if not self.tracer.enabled:
-            return None
-        return achieved_overlap_fraction(self.tracer.events())
-
-    def chrome_trace(self) -> Dict[str, Any]:
-        """The Chrome trace-event JSON object (empty when obs is off)."""
-        return self.tracer.chrome_trace()
-
-    def dump_trace(self, path: str) -> int:
-        """Write the Chrome trace JSON; returns the event count.
-        Blocking file I/O — only call between/after iterations (the
-        analyzer flags it inside async dispatch windows)."""
-        return self.tracer.dump_trace(path)
