@@ -36,9 +36,10 @@ batch:
   slots (the jnp scatter here is the interpret-mode stand-in for
   ``repro.kernels.scatter_blocks``; ``gather_row_blocks`` mirrors
   ``repro.kernels.gather_blocks``), and ``drop_blocks`` zeroes evicted
-  blocks so HBM eviction actually destroys device-resident data.  Block
-  metadata is never dropped: DSA scoring stays exact while block *data*
-  moves through the hierarchy.
+  blocks (one dispatch of one jitted, donated program, ``_BlockDrop``) so
+  HBM eviction actually destroys device-resident data.  Block metadata is
+  never dropped: DSA scoring stays exact while block *data* moves through
+  the hierarchy.
 
 The legacy stack/unstack path survives behind
 ``EngineConfig.decode_plane="stacked"`` as the equivalence oracle.
@@ -57,8 +58,9 @@ import numpy as np
 from repro.models import model as M
 from repro.obs import tracing
 
-# Route row-slot block movement through the Pallas kernels
-# (kernels/gather_blocks.py / scatter_blocks.py, _hkv variants).  Default is
+# Route row-slot block restores and gathers through the Pallas kernels
+# (kernels/gather_blocks.py / scatter_blocks.py, _hkv variants; block drops
+# always run the one ``_BlockDrop`` program).  Default is
 # the jnp path.  The kernels compile through Mosaic on TPU and run
 # interpreted on CPU (correct but slow); whether the compiled DMA stream
 # beats the jnp gather on the chip is not measured yet, so the switch stays
@@ -302,6 +304,59 @@ def staged_fns_for(cfg, attn_impl: str, plane_mesh=None) -> _StagedDecodeFns:
     return _STAGED_FNS[key]
 
 
+class _BlockDrop:
+    """The block drop as one jitted program: zero the masked blocks of one
+    batch row of a layer's pools (``k``, and ``v`` where the layer has
+    one) and leave every other byte as it was.
+
+    Its inputs have fixed shapes — the row as an int32 scalar, the blocks
+    as a ``(nb_cap,)`` bool mask — so it traces once per pool shape and
+    dtype and compiles once per shape, dtype and sharding, whatever the
+    number of blocks.  The row is read, masked and written back with
+    dynamic-slice updates, which keep the pools' sharding; the pools are
+    donated on accelerator backends so XLA updates the row in place.
+    ``trace_count`` counts traces (a trace-time side effect) and
+    ``calls`` dispatches.  The device trace names it
+    ``jit_drop_row_blocks``.
+    """
+
+    def __init__(self):
+        self.trace_count = 0
+        self.calls = 0
+        self.donated = (0,)                 # the pools
+        self.donate_active = jax.default_backend() != "cpu"
+
+        def drop_row_blocks(pools, row, mask):
+            self.trace_count += 1           # trace-time side effect only
+            keep = ~mask[None, :, None, None]       # over (H, NB, bs, D)
+
+            def zero(pool):
+                r = jax.lax.dynamic_index_in_dim(pool, row, 0,
+                                                 keepdims=False)
+                r = jnp.where(keep, r, jnp.zeros((), pool.dtype))
+                return jax.lax.dynamic_update_index_in_dim(pool, r, row, 0)
+            return {key: zero(pool) for key, pool in pools.items()}
+
+        self._jit = jax.jit(drop_row_blocks, donate_argnums=(
+            self.donated if self.donate_active else ()))
+
+    def __call__(self, pools, row, mask):
+        self.calls += 1
+        return self._jit(pools, row, mask)
+
+
+_BLOCK_DROP: Optional[_BlockDrop] = None
+
+
+def block_drop() -> _BlockDrop:
+    """The process's one block-drop program, built on first use (building
+    it asks JAX for the backend)."""
+    global _BLOCK_DROP
+    if _BLOCK_DROP is None:
+        _BLOCK_DROP = _BlockDrop()
+    return _BLOCK_DROP
+
+
 def gather_row_blocks(pool: jax.Array, row: int, blocks) -> jax.Array:
     """Gather `blocks` of one batch row: (B,H,NB,bs,D) -> (H,K,bs,D).
 
@@ -319,7 +374,9 @@ def scatter_row_blocks(pool: jax.Array, row: int, blocks,
                        payload: jax.Array) -> jax.Array:
     """Scatter `payload` (H,K,bs,D) into `blocks` of one batch row in place.
 
-    FlashD2H / H2D-restore direction: whole-block granularity, untouched
+    H2D-restore direction (the kernel route of ``restore_blocks_fused``,
+    and the eager reference the block drop is tested against):
+    whole-block granularity, untouched
     blocks preserved.  With ``REPRO_PLANE_KERNEL=1`` this runs the Pallas
     ``scatter_blocks_hkv`` kernel (pool aliased in place), otherwise the
     equivalent jnp scatter."""
@@ -355,6 +412,7 @@ class DevicePoolPlane:
         self.plane_mesh = plane_mesh
         self.decode_fn = decode_fn_for(cfg, attn_impl)
         self.staged_fns = staged_fns_for(cfg, attn_impl, plane_mesh)
+        self.block_drop = block_drop()
         self.state: Optional[Dict] = None
         self.b_cap = 0
         self.nb_cap = 0
@@ -763,20 +821,26 @@ class DevicePoolPlane:
                     blocks: List[int]) -> None:
         """Zero evicted blocks' device data (HBM eviction -> device memory
         actually dropped).  Block METADATA is kept resident so DSA scoring
-        stays exact; re-selected blocks come back via ``restore_blocks``."""
+        stays exact; re-selected blocks come back via ``restore_blocks``.
+
+        One dispatch of the block-drop program (``_BlockDrop``): the row
+        and a block mask built with numpy are its two host-to-device
+        inputs, so nothing here compiles per block count, waits on the
+        device, or builds a zero payload.  An empty list dispatches
+        nothing."""
+        if not blocks:
+            return
         tr = self.tracer
-        row = self.rows[req_id]
         c = self.state["caches"][layer]
         with tr.span("plane.drop", req=req_id, layer=layer,
                      blocks=len(blocks)):
             with tr.span("plane.drop.prepare"):
-                idx = jnp.asarray(blocks, jnp.int32)
-                zero = jnp.zeros((c["k"].shape[1], len(blocks))
-                                 + c["k"].shape[3:], c["k"].dtype)
+                mask = np.zeros(c["k"].shape[2], bool)
+                mask[np.asarray(blocks, np.int64)] = True
+                row = np.int32(self.rows[req_id])
+                pools = {key: c[key] for key in ("k", "v") if key in c}
             with tr.span("plane.drop.scatter"):
-                c["k"] = scatter_row_blocks(c["k"], row, idx, zero)
-                if "v" in c:
-                    c["v"] = scatter_row_blocks(c["v"], row, idx, zero)
+                c.update(self.block_drop(pools, row, mask))
         self.blocks_dropped += len(blocks)
 
     # -- introspection -----------------------------------------------------

@@ -479,6 +479,12 @@ STAGED_DONATED_STAGES: Dict[str, Tuple[int, ...]] = {
     "recurrent-rwkv": (2,),
 }
 
+# the block-drop program (device_pool._BlockDrop) consumes and returns the
+# layer's pools (arg 0): donated so the zeroed row is written in place
+# instead of copying the layer's pools per drop.  Checked against its
+# ``donated`` by the same assertion.
+BLOCK_DROP_DONATED: Tuple[int, ...] = (0,)
+
 
 def staged_stage_kinds(cfg) -> int:
     """Distinct stage kinds of the staged decode pipeline for ``cfg`` —

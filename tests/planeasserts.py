@@ -6,6 +6,7 @@ at runtime and the ones proven statically can never drift apart.  Before
 this module each test file hand-rolled its own copies.
 """
 from repro.core import plane_contract as pc
+from repro.core.device_pool import block_drop
 
 
 def assert_cache_hit_invariant(fns):
@@ -33,10 +34,17 @@ def staged_stage_kinds(cfg) -> int:
 def assert_donation_contract(fns):
     """Every pool-updating stage of a live registry DECLARES buffer
     donation on its pool/cache argument, exactly as the contract's
-    ``STAGED_DONATED_STAGES`` table says — on accelerator backends XLA
-    then updates the pool in place instead of copying a pool-sized buffer
-    per layer per iteration (on CPU the declaration is recorded but not
+    ``STAGED_DONATED_STAGES`` table says, and so does the block-drop
+    program (``BLOCK_DROP_DONATED``) — on accelerator backends XLA then
+    updates the pool in place instead of copying a pool-sized buffer per
+    layer per iteration (on CPU the declaration is recorded but not
     armed: CPU buffers are not donatable)."""
+    drop = block_drop()
+    assert drop.donated == pc.BLOCK_DROP_DONATED, (
+        f"block drop declares donate_argnums {drop.donated}, contract "
+        f"says {pc.BLOCK_DROP_DONATED}")
+    assert drop.donate_active == fns.donate_active, (
+        "block drop and stage registry disagree on arming donation")
     for stage, donate in pc.STAGED_DONATED_STAGES.items():
         if stage not in fns.donated:
             continue                     # stage absent for this arch family

@@ -5,9 +5,11 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.device_pool import (BucketingPolicy, DevicePoolPlane,
-                                    gather_row_blocks)
+                                    block_drop, gather_row_blocks,
+                                    scatter_row_blocks)
 from repro.models import model as M
 
 import planeasserts as pa
@@ -226,3 +228,75 @@ def test_jit_retraces_bounded_by_buckets(smoke_setup):
     pa.assert_cache_hit_invariant(fn)
     n_buckets = len({1, 2}) * 1                    # batch buckets x nb buckets
     assert fn.trace_count <= n_buckets
+
+
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "minicpm3-4b"])
+@pytest.mark.parametrize("case", ["one", "unsorted", "row", "capacity",
+                                  "empty"])
+def test_drop_blocks_matches_eager_scatter(smoke_setup, arch, case):
+    """The one-program drop zeroes exactly what the eager
+    ``scatter_row_blocks(pool, row, idx, zeros)`` zeroes, byte for byte,
+    in k and (where the layer has one; MLA has not) v; the other row is
+    untouched; it never reads the device back; an empty list dispatches
+    nothing."""
+    cfg, params = smoke_setup(arch)
+    plane = DevicePoolPlane(cfg, BucketingPolicy(block_bucket=12))
+    # 120 tokens put data in each of the 4 blocks (32 tokens a block)
+    plane.admit("a", _prefill_state(cfg, params, 120, 4))
+    plane.admit("b", _prefill_state(cfg, params, 120, 4, seed=1))
+    layer = plane.pool_layers()[0]
+    c = plane.state["caches"][layer]
+    assert ("v" in c) == (cfg.attention_type != "mla")
+    # "row": every block the prefill filled; "capacity": every block the
+    # padded row holds, the longest list a drop can get
+    blocks = {"one": [2], "unsorted": [3, 0, 2], "row": list(range(4)),
+              "capacity": list(range(plane.nb_cap))[::-1],
+              "empty": []}[case]
+    row = plane.rows["a"]
+    before = {key: c[key] for key in ("k", "v") if key in c}
+    expect = {}
+    for key, pool in before.items():
+        zero = jnp.zeros((pool.shape[1], len(blocks)) + pool.shape[3:],
+                         pool.dtype)
+        expect[key] = np.asarray(scatter_row_blocks(pool, row, blocks, zero))
+    before = {key: np.asarray(pool) for key, pool in before.items()}
+    for key, pool in before.items():       # every listed filled block
+        assert np.all(np.abs(pool[row][:, [b for b in blocks if b < 4]])
+                      .sum(axis=(0, 2, 3)) > 0), key
+    calls = block_drop().calls
+    with jax.transfer_guard_device_to_host("disallow"):
+        plane.drop_blocks("a", layer, blocks)
+    assert block_drop().calls == calls + (1 if blocks else 0)
+    assert plane.blocks_dropped == len(blocks)
+    for key, want in expect.items():
+        got = np.asarray(c[key])
+        assert got.dtype == want.dtype
+        assert got.tobytes() == want.tobytes(), key
+        other = 1 - row
+        assert got[other].tobytes() == before[key][other].tobytes(), key
+        if not blocks:
+            assert got.tobytes() == before[key].tobytes(), key
+
+
+def test_drop_blocks_traces_once_per_pool_shape(smoke_setup):
+    """Every drop count on one plane runs one program, traced once; a
+    plane with another block capacity traces it once more."""
+    cfg, params = smoke_setup("qwen2-0.5b")
+    fn = block_drop()
+    # block capacities no other test's plane has, so these traces are new
+    planes = []
+    for bucket in (13, 17):
+        plane = DevicePoolPlane(cfg, BucketingPolicy(block_bucket=bucket))
+        plane.admit("a", _prefill_state(cfg, params, 40, 4))
+        planes.append(plane)
+    traces = fn.trace_count
+    first = planes[0]
+    for layer in first.pool_layers()[:2]:
+        for n in range(1, first.nb_cap + 1):
+            first.drop_blocks("a", layer, list(range(n)))
+    assert fn.trace_count == traces + 1
+    second = planes[1]
+    for n in (1, second.nb_cap):
+        second.drop_blocks("a", second.pool_layers()[0], list(range(n)))
+    assert fn.trace_count == traces + 2
+    pa.assert_donation_contract(first.staged_fns)

@@ -113,6 +113,33 @@ def test_sharded_code_path_on_one_device(smoke_setup):
     pa.assert_cache_hit_invariant(plane.staged_fns)
 
 
+def test_sharded_drop_keeps_pool_sharding(smoke_setup, monkeypatch):
+    """The one-program block drop hands each sharded pool back with the
+    sharding it took (so donation aliases the buffer), on the eviction-
+    pressure engine of ``test_sharded_staged_eviction_pressure_oracle_exact``
+    (a 4-way model axis under the multi-device job, 1-way here)."""
+    from repro.core.device_pool import DevicePoolPlane
+    cfg, params = smoke_setup("qwen2-0.5b")
+    seen = []
+    real = DevicePoolPlane.drop_blocks
+
+    def checked(self, req_id, layer, blocks):
+        c = self.state["caches"][layer]
+        before = {key: c[key].sharding for key in ("k", "v") if key in c}
+        real(self, req_id, layer, blocks)
+        seen.append((before, {key: c[key].sharding for key in before}))
+    monkeypatch.setattr(DevicePoolPlane, "drop_blocks", checked)
+    e_c, _ = _run_engine(cfg, params, (64, 64, 64), gen=3,
+                         hbm_blocks_per_request=1,
+                         mesh_spec=f"model={min(4, N_DEV)}")
+    assert e_c.plane_mesh is not None
+    assert seen
+    for before, after in seen:
+        assert after == before
+    assert any(isinstance(s, jax.sharding.NamedSharding)
+               for before, _ in seen for s in before.values())
+
+
 # ---------------------------------------------------------------------------
 # Sharded staged decode == staged == sequential (forced multi-device CPU)
 # ---------------------------------------------------------------------------
