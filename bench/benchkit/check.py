@@ -93,17 +93,35 @@ def kv_rel_err(saved: np.ndarray, ref: np.ndarray) -> float:
     return float(np.abs(saved - ref).max()) / max(scale, 1e-30)
 
 
-def add_kv(readings: Readings, key, k, v, ref_k, ref_v, S: int) -> None:
-    """Read one layer's KV (T, Hkv, D) of request ``key`` against the
-    reference's: positions before S as ``kv_err``, the rest, position by
-    position, as ``kv_err_decode``."""
-    readings.add("kv_err", [kv_rel_err(k[:S], ref_k[:S]),
-                            kv_rel_err(v[:S], ref_v[:S])])
-    if len(ref_k) > S:
-        errs = [np.abs(x[S:] - r[S:]).max(axis=(1, 2))
+def add_kv(readings: Readings, key, saved: Dict[str, np.ndarray],
+           ref: Dict[str, np.ndarray], S: int) -> None:
+    """Read one layer's cached state of request ``key`` against the
+    reference's, each ``{name: (T, H, D)}`` as the host pool names it
+    (``k`` and ``v``, or a latent ``k`` alone): positions before S as
+    ``kv_err``, one reading per name, the rest, position by position and
+    the largest over names, as ``kv_err_decode``."""
+    readings.add("kv_err", [kv_rel_err(saved[name][:S], r[:S])
+                            for name, r in ref.items()])
+    T = min(len(r) for r in ref.values())
+    if T > S:
+        errs = [np.abs(saved[name][S:] - r[S:]).max(axis=(1, 2))
                 / max(float(np.abs(r[S:]).max()), 1e-30)
-                for x, r in ((k, ref_k), (v, ref_v))]
-        readings.add_positions("kv_err_decode", key, np.maximum(*errs))
+                for name, r in ref.items()]
+        readings.add_positions("kv_err_decode", key,
+                               np.max(np.stack(errs), axis=0))
+
+
+def pool_kv(pool, layer: int, ref: Dict[str, np.ndarray]
+            ) -> Dict[str, np.ndarray]:
+    """What a host pool holds of ``layer`` under each name of ``ref``, at
+    ref's positions: the pool's (L, H, blocks, block size, D) array of
+    that name as (T, H, D)."""
+    out = {}
+    for name, r in ref.items():
+        x = getattr(pool, name)[layer]              # (H, NB, bs, D)
+        x = x.reshape(x.shape[0], -1, x.shape[-1])[:, :len(r)]
+        out[name] = x.transpose(1, 0, 2)
+    return out
 
 
 def verdict(readings: Readings, limits: Dict[str, Dict]) -> Dict:

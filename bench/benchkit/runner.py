@@ -27,7 +27,6 @@ import numpy as np
 from benchkit import check as C
 from benchkit import flops as F
 from benchkit import timeline as TL
-from benchkit.model import Shapes
 from benchkit.spec import ROOT, Cell, SpecError, load_json
 
 CACHE_DIR = ROOT / ".jax_cache"
@@ -208,7 +207,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     mon = compile_monitor()
     setup: Dict[str, float] = {"imports_s": clock() - t_process0}
-    shapes = Shapes(cell.config)
+    shapes = cell.shapes
     set_precision(cell.config)
     spans = TR.Spans() if trace else None
     if spans is not None:
@@ -220,7 +219,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     setup["init_s"] = clock() - t
 
     t = clock()
-    mcfg = MD.model_config(cell.config, cell.config_entry["name"])
+    mcfg = MD.model_config(cell.config, cell.config_entry["name"],
+                           cell.root)
     ecfg = MD.engine_config(cell.config)
     eng = ServingEngine(params, mcfg, ecfg)
     traffic = Traffic(cell.traffic, shapes.vocab, seed)
@@ -418,30 +418,24 @@ def check_outputs(cell, shapes, seed, traffic, records, served, retained,
         toks = served[r.rid][:len(r.stamps)]
         if first_only:
             toks = toks[:1]
-        ref_kv: Dict[int, tuple] = {}
+        ref_kv: Dict[int, Dict[str, np.ndarray]] = {}
         kv_cb = None
         if kv:
             pool = retained[r.rid]
             pool.flush()
 
-            def kv_cb(layer, k, v, pool=pool, S=S, rid=r.rid):
-                # k, v (T, Hkv, D): the prompt's S positions, then those
+            def kv_cb(layer, ref, pool=pool, S=S, rid=r.rid):
+                # each (T, H, D): the prompt's S positions, then those
                 # the decode steps wrote back
-                pk, pv = pool.k[layer], pool.v[layer]   # (Hkv, NB, bs, D)
-                T = k.shape[0]
-                sk = pk.reshape(pk.shape[0], -1, pk.shape[-1])[:, :T]
-                sv = pv.reshape(pv.shape[0], -1, pv.shape[-1])[:, :T]
-                C.add_kv(readings, rid, sk.transpose(1, 0, 2),
-                         sv.transpose(1, 0, 2), k, v, S)
+                C.add_kv(readings, rid, C.pool_kv(pool, layer, ref), ref, S)
                 if control:
-                    ref_kv[layer] = (k, v)
+                    ref_kv[layer] = ref
         logits = R.run(params, shapes, prompt, toks, on_kv=kv_cb,
                        bucket=bucket)
         readings.add_tokens(logits, toks)
         if control:
-            def ctl_kv(layer, k, v, S=S, rid=r.rid):
-                rk, rv = ref_kv[layer]
-                C.add_kv(ctl, rid, k, v, rk, rv, S)
+            def ctl_kv(layer, low, S=S, rid=r.rid):
+                C.add_kv(ctl, rid, low, ref_kv[layer], S)
             low = R.run(params, shapes, prompt, toks, dtype=low_dtype,
                         precision=low_prec,
                         on_kv=ctl_kv if kv else None, bucket=bucket)
@@ -487,7 +481,7 @@ def main(argv, t_process0: float) -> int:
     args = parse(argv)
     try:
         cell = Cell(args.workload)
-    except (SpecError, OSError, KeyError) as e:
+    except (SpecError, OSError, KeyError, ValueError) as e:
         note(f"cannot load the cell: {e}")
         return 2
     if not (ROOT / "src" / "repro").is_dir():

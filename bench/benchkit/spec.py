@@ -8,14 +8,40 @@ files behind those names are:
 * ``bench/traffic/<traffic>.json`` -- parameters for ``traffic.Traffic``;
 * ``bench/checks/<cell>.json`` -- the numbers the output check compares
   and their limits;
-* ``bench/metrics/<metric>.py`` -- one reader per metric, ``read(ctx)``.
+* ``bench/metrics/<metric>.py`` -- one reader per metric, ``read(ctx)``;
+* ``bench/archs/<model_type>.py`` -- everything that knows one
+  architecture, found by the configuration's ``model_type``.  Such a
+  module provides
 
-Adding a cell, mix, configuration or metric adds files and entries only.
+  - ``read_shapes(s, cfg)``: set the architecture's sizes on the
+    ``model.Shapes`` ``s`` from its configuration keys;
+  - ``model_config(s, cfg, name, dsa)``: the program's ``ModelConfig``;
+  - ``weights(s, key, dtype)``: the weights in the pytree the program
+    serves, drawn from ``key`` (traced inside one jitted call);
+  - ``embed(params, ids)``, ``layer_params(params, layer)`` and
+    ``head_params(params)``: the parts of that pytree the reference reads;
+  - ``layer_kind(s, layer)``: a name for the kind of layer ``layer`` is;
+    layers of one kind share one reference program;
+  - ``reference_layer(s, kind, ops, S, n)``: that kind's layer over a
+    prompt of ``S`` positions and ``n`` decode positions, returning the
+    new hidden states and the cached state as the host pool names it,
+    ``{name: (T, heads, width)}`` (``k`` and ``v``, or ``k`` alone for a
+    latent cache), built from ``reference``'s shared helpers (norm,
+    rotary embedding, blocked causal attention, DSA decode attention,
+    which take the softmax scale, any number of query heads per key head
+    and values narrower than keys);
+  - ``reference_head(s, ops)``: the final norm and the logits;
+  - ``layer_counts(s, layer)``: the operations of layer ``layer`` (see
+    ``flops``).
+
+Adding a cell, mix, configuration, metric or architecture adds files and
+entries only.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
+import re
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional
 
@@ -56,6 +82,8 @@ class Cell:
                                  / f"{self.traffic_name}.json")
         self.check = load_json(self.root / "bench" / "checks"
                                / f"{name}.json")
+        from benchkit.model import Shapes
+        self.shapes = Shapes(self.config, self.root)
 
     def metrics(self, trace: bool) -> List[Dict[str, Any]]:
         """The metric entries this cell reports: its end-to-end metrics in
@@ -75,13 +103,28 @@ class Cell:
         return load_reader(self.root, metric)
 
 
+def _load(path: Path, prefix: str, name: str):
+    mod_name = prefix + "".join(c if c.isalnum() else "_" for c in name)
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(root: Path, metric: str):
     path = Path(root) / "bench" / "metrics" / f"{metric}.py"
     if not path.is_file():
         raise SpecError(f"no reader for metric {metric!r} at {path}")
-    mod_name = "bench_metric_" + "".join(
-        c if c.isalnum() else "_" for c in metric)
-    spec = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(path, "bench_metric_", metric).read
+
+
+def load_arch(root: Path, model_type: str):
+    """The module ``bench/archs/<model_type>.py`` under ``root``."""
+    archs = Path(root) / "bench" / "archs"
+    path = (archs / f"{model_type}.py").resolve()
+    if not (re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]*", str(model_type))
+            and path.is_file()):
+        known = sorted(p.stem for p in archs.glob("*.py"))
+        raise SpecError(f"no architecture module for model_type "
+                        f"{model_type!r} under {archs}; known: {known}")
+    return _load(path, "bench_arch_", model_type)

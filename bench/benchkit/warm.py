@@ -15,6 +15,9 @@ for every count a step can ask at the plane's capacity:
 * drop: 1 .. min(blocks, twice that per-row bound + the LRU's pairs),
   since one layer's pending drops are blocks the step selected, blocks
   the LRU held before it, and blocks kept back from the last stage.
+
+Each stand-in records its spans with a ``Tracer`` of its own, so the
+warm-up's restores and drops stay out of the engine's counters.
 """
 from __future__ import annotations
 
@@ -41,6 +44,7 @@ def warm(planes: List, lru_pairs: int, threads: int = 0) -> Dict[str, int]:
     import jax
     import numpy as np
     from repro.core import device_pool as dp
+    from repro.obs.tracing import Tracer
     threads = threads or min(8, os.cpu_count() or 1)
     done = {"restore": 0, "drop": 0}
     for plane in planes:
@@ -55,6 +59,7 @@ def warm(planes: List, lru_pairs: int, threads: int = 0) -> Dict[str, int]:
 
         def counts(ks: List[int]) -> None:
             sb = copy.copy(plane)
+            sb.tracer = Tracer()
             sb.state = {"caches": {layer: {key: v.copy()
                                            for key, v in c.items()}}}
             sb.rows = {f"warm{r}": r for r in range(plane.b_cap)}

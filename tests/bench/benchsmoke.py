@@ -2,7 +2,8 @@
 
 ``make(tmp)`` writes a ``BENCHMARK.json`` with two cells on a two-layer
 configuration of the qwen2 file's keys, traffic and check files beside
-it, and links the real metric readers, peaks table and program, so a
+it, and links the real metric readers, architecture modules, peaks table
+and program, so a
 test drives the harness's own code at a size a test run can hold.
 """
 from __future__ import annotations
@@ -26,7 +27,7 @@ def make(root: Path, limits=None) -> Path:
     root = Path(root)
     for d in ("configs", "traffic", "checks"):
         (root / "bench" / d).mkdir(parents=True, exist_ok=True)
-    for name in ("metrics", "peaks.json"):
+    for name in ("metrics", "archs", "peaks.json"):
         os.symlink(BENCH / name, root / "bench" / name)
     os.symlink(REPO / "src", root / "src")
     cfg = json.loads((BENCH / "configs" / "qwen2-0.5b.json").read_text())
@@ -69,9 +70,11 @@ def make(root: Path, limits=None) -> Path:
               metric("hbm_hit_rate.decode", "%", "output_tok_s", dec),
               metric("mfu.prefill", "%", "ttft_p50_s", pre)]}
     (root / "BENCHMARK.json").write_text(json.dumps(bm))
+    # decode sessions outlast a one-second window on any CPU (a fast one
+    # takes ~80 steps in it), as the decode cells' sessions outlast theirs
     traffic = {
         "decode": {"clients": 3, "prompt_tokens": {"fixed": 200},
-                   "max_new_tokens": 64, "next_on": "finish",
+                   "max_new_tokens": 256, "next_on": "finish",
                    "setup": {"then_steps": 2}},
         "prefill": {"clients": 2, "prompt_tokens": {"stratified": {
             "n": 8, "clip": [40, 300],

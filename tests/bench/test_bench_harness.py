@@ -1,6 +1,6 @@
-"""The benchmark harness's own arithmetic, on the CPU: finding cells by
-name, traffic, end-to-end numbers from host stamps, the trace reduction,
-operation counts, and refusing to run without a TPU."""
+"""The benchmark harness's own arithmetic, on the CPU: finding cells and
+architectures by name, traffic, end-to-end numbers from host stamps, the
+trace reduction, operation counts, and refusing to run without a TPU."""
 import json
 import os
 import shutil
@@ -15,7 +15,7 @@ from benchkit import flops as F
 from benchkit import timeline as TL
 from benchkit import trace as TR
 from benchkit.model import Shapes
-from benchkit.spec import Cell, load_json
+from benchkit.spec import Cell, SpecError, load_json
 from benchkit.traffic import Traffic, prompt_lengths
 
 REPO = benchsmoke.REPO
@@ -114,6 +114,277 @@ def test_a_mix_with_output_lengths_of_its_own_is_data_only(tmp_path):
             assert sorted(t.request(c, i)[1] for i in range(4)) == outs
         assert Traffic(mix, 1000, seed).request(5, 9)[1] == \
             t.request(5, 9)[1]
+
+
+# -- architectures ------------------------------------------------------------
+
+TOY_ARCH = '''
+"""A toy architecture with what DeepSeek-V2-Lite brings: layers of two
+kinds (a dense FFN in layer 0 only), weights in list mode, a latent-only
+cache (one key head, values the latent's first part), 4 query heads per
+key head and a softmax scale of its own."""
+import jax
+import jax.numpy as jnp
+from benchkit import reference as R
+
+
+def read_shapes(s, cfg):
+    s.heads = int(cfg["num_attention_heads"])
+    s.latent = int(cfg["kv_lora_rank"])
+    s.rope_dim = int(cfg["qk_rope_head_dim"])
+    s.ff = int(cfg["intermediate_size"])
+
+
+def model_config(s, cfg, name, dsa):
+    return {"arch": "toy", "name": name, "block_size": dsa.block_size}
+
+
+def weights(s, key, dt):
+    ks = iter(jax.random.split(key, 8 * s.layers + 2))
+    dk = s.latent + s.rope_dim
+
+    def normal(shape, std):
+        return (jax.random.normal(next(ks), shape, jnp.float32) * std
+                ).astype(dt)
+    layers = []
+    for l in range(s.layers):
+        p = {"norm": 1.0 + normal((s.d,), 0.1),
+             "w_kv": normal((s.d, dk), s.d ** -0.5),
+             "w_q": normal((s.d, s.heads * dk), s.d ** -0.5),
+             "w_o": normal((s.heads * s.latent, s.d), 0.1)}
+        if layer_kind(s, l) == "dense":
+            p["w_up"] = normal((s.d, s.ff), s.d ** -0.5)
+            p["w_down"] = normal((s.ff, s.d), s.ff ** -0.5)
+        layers.append(p)
+    return {"embed": normal((s.vocab, s.d), 0.02), "layers": layers}
+
+
+def embed(params, ids):
+    return params["embed"][ids]
+
+
+def layer_params(params, layer):
+    return params["layers"][layer]
+
+
+def head_params(params):
+    return {"embed": params["embed"]}
+
+
+def layer_kind(s, layer):
+    return "dense" if layer == 0 else "attn_only"
+
+
+def reference_layer(s, kind, ops, S, n):
+    H, Dv = s.heads, s.latent
+    Dk = Dv + s.rope_dim
+    scale = 0.7 ** 2 * Dk ** -0.5
+    T = S + n
+
+    def layer(p, h):
+        pos = jnp.arange(T)
+        x = R.rms(h, p["norm"], s.eps)
+        c = ops.mm(x, p["w_kv"]).reshape(T, 1, Dk)
+        c = jnp.concatenate([c[..., :Dv], R.rope(c[..., Dv:], pos, 1e4)],
+                            -1)
+        q = ops.mm(x, p["w_q"]).reshape(T, H, Dk)
+        o = R.prompt_attention(q[:S], c[:S], c[:S, :, :Dv], scale, ops)
+        if n:
+            o = jnp.concatenate([o, R.dsa_attention(
+                q[S:], c, c[:, :, :Dv], S, scale, s, ops)], 0)
+        h = h + ops.mm(o, p["w_o"])
+        if kind == "dense":
+            h = h + ops.mm(jax.nn.silu(ops.mm(x, p["w_up"])), p["w_down"])
+        return h, {"k": c}
+    return layer
+
+
+def reference_head(s, ops):
+    def head(params, h):
+        return jnp.matmul(h, params["embed"].T, precision=ops.prec,
+                          preferred_element_type=jnp.float32)
+    return head
+
+
+def layer_counts(s, layer):
+    dk = s.latent + s.rope_dim
+    mm = s.d * dk + s.d * s.heads * dk + s.heads * s.latent * s.d
+    if layer_kind(s, layer) == "dense":
+        mm += 2 * s.d * s.ff
+    return {"matmul_params": mm, "pair_ops": 2.0 * s.heads * (dk + s.latent),
+            "score_ops": 4.0 * s.heads * dk,
+            "attend_ops": 2.0 * s.heads * (dk + s.latent)}
+'''
+
+TOY_CFG = {"source": "toy", "model_type": "toy", "hidden_size": 32,
+           "num_hidden_layers": 3, "num_attention_heads": 4,
+           "kv_lora_rank": 16, "qk_rope_head_dim": 8,
+           "intermediate_size": 48, "vocab_size": 64,
+           "tie_word_embeddings": True, "rms_norm_eps": 1e-6,
+           "torch_dtype": "float32", "matmul_precision": "highest",
+           "reduced": [], "published": {},
+           "dsa": {"block_size": 4, "token_budget": 16,
+                   "metadata": "cuboid", "sink_blocks": 1,
+                   "recent_blocks": 2}}
+
+
+def _files(root):
+    return {p.relative_to(root): p.read_bytes()
+            for p in root.rglob("*") if p.is_file()
+            and "__pycache__" not in p.parts}
+
+
+def test_adding_an_architecture_takes_only_new_files(tmp_path, monkeypatch):
+    import jax
+    from benchkit import model as MD
+    from benchkit import reference as R
+    root = tmp_path / "co"
+    shutil.copytree(REPO / "bench", root / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = _files(root / "bench")
+    (root / "bench/archs/toy.py").write_text(TOY_ARCH)
+    (root / "bench/configs/toy.json").write_text(json.dumps(TOY_CFG))
+    (root / "bench/checks/toy.decode_16k.json").write_text(json.dumps(
+        {"limits": {"first_gap": {"limit": 0.1}}}))
+    bm = json.loads((REPO / "BENCHMARK.json").read_text())
+    bm["configs"].append({"name": "toy", "source": "toy",
+                          "file": "bench/configs/toy.json", "reduced": [],
+                          "why": "x"})
+    bm["workloads"].append({"name": "toy.decode_16k", "config": "toy",
+                            "traffic": "decode_16k", "chips": 1,
+                            "why": "x"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bm))
+    after = _files(root / "bench")
+    # no file the harness had was edited; three were added
+    assert {k: after[k] for k in before} == before
+    assert sorted(map(str, set(after) - set(before))) == [
+        "archs/toy.py", "checks/toy.decode_16k.json", "configs/toy.json"]
+
+    cell = Cell("toy.decode_16k", root)
+    toy = cell.shapes.arch
+    assert toy.__file__ == str((root / "bench/archs/toy.py").resolve())
+    s = MD.Shapes(cell.config, root)
+    assert s.arch.__file__ == toy.__file__
+    assert (s.latent, s.rope_dim, s.heads) == (16, 8, 4)
+    assert MD.model_config(cell.config, "toy", root) == {
+        "arch": "toy", "name": "toy", "block_size": 4}
+    params = MD.init_weights(s, 2 ** 33 + 3)
+    assert isinstance(params["layers"], list)
+    assert "w_up" in params["layers"][0] and "w_up" not in params["layers"][1]
+    assert jax.tree.all(jax.tree.map(
+        lambda a, b: bool((a == b).all()), params,
+        MD.init_weights(s, 2 ** 33 + 3)))
+
+    kinds = []
+    layer_fn = R._layer_fn
+
+    def spy(s_, kind, *a):
+        kinds.append(kind)
+        return layer_fn(s_, kind, *a)
+    monkeypatch.setattr(R, "_layer_fn", spy)
+    seen = {}
+    prompt = np.arange(37) % 64
+    served = [5, 9, 2, 60, 1, 7]
+    logits = R.run(params, s, prompt, served,
+                   on_kv=lambda l, kv: seen.__setitem__(l, kv))
+    assert logits.shape == (6, 64) and np.isfinite(logits).all()
+    # the latent alone is cached: one key head, keys 24 wide
+    assert sorted(seen) == [0, 1, 2]
+    assert {n: x.shape for n, x in seen[1].items()} == {"k": (42, 1, 24)}
+    # each layer ran its own kind's reference program
+    assert kinds == ["dense", "attn_only", "attn_only"]
+    # teacher forcing: the first served token's logits are the prompt's
+    one = R.run(params, s, prompt, served[:1])
+    np.testing.assert_allclose(one[0], logits[0], rtol=1e-5, atol=1e-5)
+
+    # the operation counts follow the toy's, layer by layer
+    dk, mm0 = 24, 32 * 24 + 32 * 4 * 24 + 4 * 16 * 32
+    assert F.dense_params_per_layer(s, 0) == mm0 + 2 * 32 * 48
+    assert F.dense_params_per_layer(s, 1) == mm0
+    ctx = 100                       # 25 blocks, 16 tokens attended
+    per = 2.0 * 4 * (dk + 16) * 16 + 4.0 * 4 * dk * 25
+    assert F.decode_token_flops(s, ctx) == (
+        2.0 * (3 * mm0 + 2 * 32 * 48) + 3 * per + 2 * 32 * 64)
+    assert F.prefill_flops(s, 10) == (
+        2.0 * (3 * mm0 + 2 * 32 * 48) * 10 + 3 * 2.0 * 4 * (dk + 16) * 55
+        + 2 * 32 * 64)
+
+
+@pytest.mark.parametrize("model_type", ["nope", "../benchkit/model"])
+def test_an_unknown_model_type_is_refused(model_type):
+    from benchkit.model import Shapes, model_config
+    cfg = load_json(REPO / "bench/configs/qwen2-0.5b.json")
+    cfg["model_type"] = model_type
+    with pytest.raises(SpecError, match=r"known: \[.*'qwen2'"):
+        Shapes(cfg)
+    with pytest.raises(SpecError, match="model_type"):
+        model_config(cfg, "x")
+
+
+def test_qwen2_weights_are_pinned():
+    """The smoke-size Qwen2 weights of one seed, as the harness drew them
+    before the architectures moved to ``bench/archs/`` (sum, sum of
+    magnitudes, one entry): the draw order is the seed's contract."""
+    from benchkit.model import init_weights
+    cfg = load_json(REPO / "bench/configs/qwen2-0.5b.json")
+    cfg.update(benchsmoke.SMOKE)
+    p = init_weights(Shapes(cfg), 1234567890123)
+    pins = {
+        "embed": (p["embed"], -2.5734274248884077, 521.6073382349034,
+                  -0.012490804307162762),
+        "final_norm": (p["final_norm"], 64.10613864660263,
+                       64.10613864660263, 1.0779674053192139),
+        "wq": (p["layers"]["attn"]["wq"], 13.88115406269435,
+               812.4488248499015, -0.0027783620171248913),
+        "bv": (p["layers"]["attn"]["bv"], -0.5505619855830446,
+               4.530983036966063, 0.0511765219271183),
+        "w_down": (p["layers"]["ffn"]["w_down"], -12.576468884418318,
+                   1153.0914727999343, 0.15686339139938354)}
+    for name, (x, total, mag, entry) in pins.items():
+        x = np.asarray(x, np.float64)
+        assert float(x.ravel()[7]) == entry, name
+        assert float(x.sum()) == pytest.approx(total, rel=1e-9, abs=1e-9)
+        assert float(np.abs(x).sum()) == pytest.approx(mag, rel=1e-9)
+
+
+@pytest.mark.parametrize("where", ["none", "prompt", "decode"])
+@pytest.mark.parametrize("names", [("k",), ("k", "v")])
+def test_kv_check_reads_each_cached_name(names, where):
+    """The KV check reads what the host pool holds under each name the
+    reference gives: a latent-only pool (``v`` None) as well as k and v."""
+    from types import SimpleNamespace
+    from benchkit import check as C
+    rng = np.random.default_rng(3)
+    L, H, NB, bs, D, S, T = 2, 1 if names == ("k",) else 2, 6, 4, 24, 12, 19
+    pool = SimpleNamespace(v=None)
+    for n in names:
+        setattr(pool, n, rng.normal(size=(L, H, NB, bs, D)).astype(np.float32))
+    layer = 1
+    ref = C.pool_kv(pool, layer, {n: np.zeros((T, H, D)) for n in names})
+    assert {n: x.shape for n, x in ref.items()} == {n: (T, H, D)
+                                                   for n in names}
+    ref = {n: x.copy() for n, x in ref.items()}
+    pos = {"prompt": 5, "decode": 15}.get(where)
+    if pos is not None:
+        blk, slot = divmod(pos, bs)
+        getattr(pool, names[-1])[layer, 0, blk, slot, 3] += 0.05
+    rd = C.Readings()
+    C.add_kv(rd, "r0", C.pool_kv(pool, layer, ref), ref, S)
+    rd.close()
+    v = C.verdict(rd, {"kv_err": {"limit": 1e-5},
+                       "kv_err_decode": {"limit": 1e-5}})
+    nums = v["numbers"]
+    assert nums["kv_err"]["n"] == len(names)
+    assert nums["kv_err_decode"]["n"] == T - S
+    assert v["ok"] is (where == "none")
+    if where == "none":
+        assert nums["kv_err"]["value"] == 0 and \
+            nums["kv_err_decode"]["value"] == 0
+    else:
+        bad = "kv_err" if where == "prompt" else "kv_err_decode"
+        assert nums[bad]["value"] > nums[bad]["limit"]
+        assert nums[{"kv_err": "kv_err_decode",
+                     "kv_err_decode": "kv_err"}[bad]]["value"] == 0
 
 
 # -- traffic ------------------------------------------------------------------
@@ -259,7 +530,7 @@ def test_flops_qwen2_5_3b_by_hand():
     assert F.dense_params_per_layer(s) == 77_070_336
     per_layer = 2 * 77_070_336 + 4 * 16 * 128 * 513 + 4 * 16 * 128 * 2048
     assert F.decode_token_flops(s, 16_385) == \
-        12 * per_layer + 2 * 2048 * 151_936
+        9 * per_layer + 2 * 2048 * 151_936
 
 
 # -- refusing to run -------------------------------------------------------------

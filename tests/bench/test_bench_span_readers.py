@@ -88,3 +88,29 @@ def test_span_times_fit_in_the_window(window):
                 if m.startswith(("drop_ms", "restore", "kv_readback",
                                  "step_self")))
     assert parts < 1000.0 * seconds / iterations
+
+
+def test_warm_up_stays_out_of_the_engines_counters(tiny_cfg, tiny_params):
+    """Set-up's block-count warm-up drives the planes' restores and drops
+    on stand-ins with recorders of their own: the engine's counted spans
+    do not see them."""
+    from benchkit import warm as WM
+    from repro.serving.engine import EngineConfig, ServingEngine
+    from repro.serving.request import Request
+    eng = ServingEngine(tiny_params, tiny_cfg, EngineConfig(
+        chunk_size=64, r_max=4, hbm_blocks_per_request=1))
+    rng = np.random.default_rng(5)
+    eng.submit(Request(prompt_len=64, max_new_tokens=8),
+               tokens=rng.integers(4, tiny_cfg.vocab_size,
+                                   64).astype(np.int32))
+    while eng.states and not all(
+            st.req.generated >= 2 for st in eng.states.values()):
+        eng.step()
+    names = ("plane.drop_calls", "plane.drop_s", "plane.restore_s")
+    snap0 = eng.metrics_snapshot()
+    done = WM.warm(list(eng.planes.values()),
+                   eng.eng.hbm_blocks_per_request, threads=2)
+    snap1 = eng.metrics_snapshot()
+    eng.close()
+    assert done["restore"] > 0 and done["drop"] > 0
+    assert {n: snap1[n] for n in names} == {n: snap0[n] for n in names}
